@@ -78,19 +78,14 @@ def provenance(benchmark: str, *, fast: Optional[bool] = None,
 
 
 def timed(fn, *args, warmup=1, iters=5) -> float:
-    """Median wall-clock seconds per call."""
+    """Median wall-clock seconds per call, each call blocked on until its
+    result is ready.  Errors (device ones included) raise."""
     for _ in range(warmup):
-        jax.block_until_ready(fn(*args)) if hasattr(
-            fn(*args), "block_until_ready"
-        ) else fn(*args)
+        jax.block_until_ready(fn(*args))
     ts = []
     for _ in range(iters):
         t0 = time.perf_counter()
-        out = fn(*args)
-        try:
-            jax.block_until_ready(out)
-        except Exception:
-            pass
+        jax.block_until_ready(fn(*args))
         ts.append(time.perf_counter() - t0)
     return float(np.median(ts))
 

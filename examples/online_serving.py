@@ -5,56 +5,14 @@ requests, comparing serial vs parallel vs fused execution modes.
     PYTHONPATH=src python examples/online_serving.py
 """
 
-import threading
 import time
 
 import numpy as np
 
 from repro.core import build_ivf
-from repro.core.scheduler import RequestRejected, RuntimeConfig, ServingRuntime
+from repro.core.runtime import RuntimeConfig, ServingRuntime
 from repro.data.synthetic import sift_like
-
-
-def drive(runtime: ServingRuntime, corpus, *, qps_search=3, qps_insert=20,
-          duration=4.0, seed=0, warmup=True):
-    if warmup:  # jit-compile the search/insert/fused steps outside the
-        # measurement window, then reset the latency stats
-        runtime.submit_search(corpus[:1]).result(timeout=60)
-        runtime.submit_insert(corpus[:4] + 0.01).result(timeout=60)
-        time.sleep(0.3)
-        runtime.reset_stats()
-    return _drive(runtime, corpus, qps_search=qps_search,
-                  qps_insert=qps_insert, duration=duration, seed=seed)
-
-
-def _drive(runtime: ServingRuntime, corpus, *, qps_search, qps_insert,
-           duration, seed=0):
-    """Open-loop Poisson traffic generator."""
-    rng = np.random.default_rng(seed)
-    t_end = time.perf_counter() + duration
-    futures, rejected = [], 0
-    next_s = time.perf_counter()
-    next_i = time.perf_counter()
-    while time.perf_counter() < t_end:
-        now = time.perf_counter()
-        if now >= next_s:
-            q = corpus[rng.integers(0, len(corpus), 1)]
-            try:
-                futures.append(runtime.submit_search(q))
-            except RequestRejected:
-                rejected += 1
-            next_s += rng.exponential(1.0 / qps_search)
-        if now >= next_i:
-            v = corpus[rng.integers(0, len(corpus), 16)] + 0.01
-            futures.append(runtime.submit_insert(v))
-            next_i += rng.exponential(16.0 / qps_insert)
-        time.sleep(0.0005)
-    for f in futures:
-        try:
-            f.result(timeout=30)
-        except Exception:
-            pass
-    return rejected
+from repro.launch.serve import serve
 
 
 def main():
@@ -64,19 +22,23 @@ def main():
             corpus, n_clusters=32, block_size=64, max_chain=64,
             capacity_vectors=40_000, nprobe=8, k=10,
         )
-        rt = ServingRuntime(
-            index,
-            # fault-tolerant serving posture (docs/serving_ops.md): bound
-            # the mutation backlog, expire requests instead of serving
-            # them arbitrarily late, and degrade before falling over
-            RuntimeConfig(mode=mode, nprobe=8, k=10, flush_min=16,
-                          flush_interval=0.1,
-                          max_pending_mutations=4096,
-                          default_deadline=5.0,
-                          degradation_ladder=("no_rerank", "half_nprobe")),
-        )
+        # fault-tolerant serving posture (docs/serving_ops.md): bound the
+        # mutation backlog, expire requests instead of serving them
+        # arbitrarily late, and degrade before falling over
+        cfg = RuntimeConfig(mode=mode, nprobe=8, k=10, flush_min=16,
+                            flush_interval=0.1,
+                            max_pending_mutations=4096,
+                            default_deadline=5.0,
+                            degradation_ladder=("no_rerank", "half_nprobe"))
+        # open-loop Poisson search + insert traffic; raises if any request
+        # fails (repro.launch.serve)
+        rep = serve(index, corpus, cfg, qps_search=3, qps_insert=20,
+                    duration=4.0)
+        s = rep.stats
+        print(f"mode={mode:<9} search {s['search'].row()}")
+        print(f"{'':15}insert {s['insert'].row()}  rejected={rep.rejected}")
+        rt = ServingRuntime(index, cfg)
         try:
-            rejected = drive(rt, corpus)
             # the mutation stream rides the same lane: deletes tombstone
             # through the device id map, updates replace in place under
             # the same id (one fused dispatch each); auto_compact reclaims
@@ -88,8 +50,6 @@ def main():
             rt.submit_update(corpus[keep] * 0.5, keep).result(timeout=30)
             time.sleep(0.2)
             s = rt.stats()
-            print(f"mode={mode:<9} search {s['search'].row()}")
-            print(f"{'':15}insert {s['insert'].row()}  rejected={rejected}")
             print(f"{'':15}mutation {s['mutation'].row()}")
             print(f"{'':15}deletes={s['deletes']} updates={s['updates']} "
                   f"live={s['live_vectors']} "

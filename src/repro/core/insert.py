@@ -37,7 +37,9 @@ from repro.core.block_pool import (
 def assign_clusters(centroids: jax.Array, vectors: jax.Array) -> jax.Array:
     """k <- argmin_c ||y - c||^2  (Alg. 2 line 5)."""
     # ||y-c||^2 = ||y||^2 - 2 y.c + ||c||^2 ; ||y||^2 constant per row.
-    dots = vectors @ centroids.T
+    # Full f32 precision, as in the search's coarse probe (core.search)
+    dots = jnp.matmul(vectors, centroids.T,
+                      precision=jax.lax.Precision.HIGHEST)
     cn = jnp.sum(centroids * centroids, axis=-1)
     return jnp.argmin(cn[None, :] - 2.0 * dots, axis=-1).astype(jnp.int32)
 

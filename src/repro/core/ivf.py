@@ -140,8 +140,9 @@ class IVFIndexConfig:
             n_blocks = self.pool_blocks
         else:
             cap = self.capacity_vectors or (self.n_clusters * self.block_size)
-            # slack: every cluster may hold a partial tail block, plus 25%
-            n_blocks = int(cap // self.block_size + self.n_clusters * 0.5 + 16)
+            # full blocks for the capacity, plus one partial tail block per
+            # list: the pool cannot run out before `cap` rows are resident
+            n_blocks = int(-(-cap // self.block_size)) + self.n_clusters + 16
         return PoolConfig(
             n_clusters=self.n_clusters,
             dim=self.dim,

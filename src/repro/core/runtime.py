@@ -886,16 +886,13 @@ class ServingRuntime:
 
     @staticmethod
     def _traced(step) -> int:
-        """Entry count of a jitted step's shape-trace cache (``-1`` when
-        the jit wrapper has no such counter).  Dispatch sites compare it
-        before/after a call to tell a fresh compile from a steady-state
-        hit: compile seconds must never poison the service EWMA the
-        adaptive stability floor is built on — one poisoned observation
-        can pin the batch window at the top rung for many dispatches."""
-        try:
-            return step._cache_size()
-        except AttributeError:
-            return -1
+        """Entry count of a jitted step's shape-trace cache.  Dispatch
+        sites compare it before/after a call to tell a fresh compile from
+        a steady-state hit: compile seconds must never poison the service
+        EWMA the adaptive stability floor is built on — one poisoned
+        observation can pin the batch window at the top rung for many
+        dispatches."""
+        return step._cache_size()
 
     # holds: _state_lock
     def _search_step_for(self, base: int, budget: Optional[int] = None,
@@ -1553,6 +1550,11 @@ class ServingRuntime:
         (the stale-batch latency bug).  The flush threshold likewise
         comes from the controller (``flush_min`` when adaptive is off).
 
+        Once the window is over, the items already waiting in the queue
+        are due as well and join this batch (up to the flush threshold):
+        after a stall — a compile, a slow dispatch — the backlog leaves in
+        one dispatch, not one dispatch per request.
+
         A running row count is kept instead of re-concatenating every
         pending payload per queue pop (that was quadratic in batch size)."""
         items: list[_Timed] = []
@@ -1562,11 +1564,14 @@ class ServingRuntime:
             window = self._controller.window()
             anchor = items[0].t_arrival if items else t_enter
             timeout = anchor + window - time.perf_counter()
-            if timeout <= 0:
-                break
             try:
-                item = self._insert_q.get(timeout=min(timeout, 0.01))
+                if timeout > 0:
+                    item = self._insert_q.get(timeout=min(timeout, 0.01))
+                else:
+                    item = self._insert_q.get_nowait()
             except queue.Empty:
+                if timeout <= 0:
+                    break
                 continue
             if item.trace is not None:
                 item.trace.stamp(STAGE_QUEUE)

@@ -64,6 +64,10 @@ from repro.core.block_pool import NULL, IVFState, PoolConfig
 from repro.core.pq import PQParams
 
 INF = jnp.float32(jnp.inf)
+# f32 contractions at full precision: at TPU default precision an f32 dot
+# is one bf16 pass, whose rounding at SIFT-like norms exceeds the gaps
+# between neighbours and reorders the probe and the top-k
+HIGHEST = jax.lax.Precision.HIGHEST
 
 # score_fn hooks have signature (state, queries, payload, probe_idx) ->
 # [Q, C, T] scores; centroids and any other index-dependent data must come
@@ -74,7 +78,9 @@ def l2_sq(queries: jax.Array, points: jax.Array) -> jax.Array:
     """[Q, D] x [N, D] -> [Q, N] squared L2 distances."""
     qn = jnp.sum(queries * queries, axis=-1, keepdims=True)
     pn = jnp.sum(points * points, axis=-1)
-    return qn + pn[None, :] - 2.0 * (queries @ points.T)
+    return qn + pn[None, :] - 2.0 * jnp.matmul(
+        queries, points.T, precision=HIGHEST
+    )
 
 
 def coarse_probe(state: IVFState, queries: jax.Array, nprobe: int):
@@ -134,7 +140,7 @@ def flat_block_scores(queries: jax.Array, payload: jax.Array) -> jax.Array:
     qn = jnp.sum(queries * queries, axis=-1)[:, None, None]
     dots = jnp.einsum(
         "qd,qctd->qct", queries.astype(payload.dtype), payload,
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=HIGHEST,
     )
     return qn + vn - 2.0 * dots
 

@@ -18,7 +18,6 @@ call indices, deterministic.
 import threading
 import time
 from concurrent.futures import Future
-from concurrent.futures import TimeoutError as FutureTimeout
 
 import numpy as np
 import pytest
@@ -648,7 +647,7 @@ def test_no_hung_future_under_combined_faults(base_index, mode):
     for i, f in enumerate(futures):
         try:
             exc = f.exception(timeout=30)
-        except (TimeoutError, FutureTimeout):  # 3.10: distinct classes
+        except TimeoutError:
             hung.append(i)
             continue
         if exc is not None:

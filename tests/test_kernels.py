@@ -131,19 +131,21 @@ def fused_index():
 
 @pytest.mark.parametrize("k", [1, 10, 100])
 def test_union_fused_bit_identical_to_union(fused_index, k):
-    """Acceptance: (dist, id) bit-identical to search_union, k in {1,10,100}."""
+    """Acceptance: union, union_fused and union_fused_scan all meet the
+    top-k contract against the IVF-exact f32-HIGHEST reference, k in
+    {1, 10, 100} (exact equality holds on no platform: tests/topk_contract)."""
+    from repro.core.reference import ivf_exact_topk, live_rows
     from repro.core.search import make_search_fn
+    from topk_contract import assert_topk_contract, id_table
 
     corpus, idx, q = fused_index
-    d0, i0 = make_search_fn(idx.pool_cfg, nprobe=4, k=k, path="union")(
-        idx.state, q
-    )
-    for path in ("union_fused", "union_fused_scan"):
-        d, i = make_search_fn(idx.pool_cfg, nprobe=4, k=k, path=path)(
+    live = live_rows(idx.state)
+    ref = ivf_exact_topk(idx.state, q, nprobe=4, k=k, live=live)
+    for path in ("union", "union_fused", "union_fused_scan"):
+        got = make_search_fn(idx.pool_cfg, nprobe=4, k=k, path=path)(
             idx.state, q
         )
-        np.testing.assert_array_equal(np.asarray(i), np.asarray(i0))
-        np.testing.assert_array_equal(np.asarray(d), np.asarray(d0))
+        assert_topk_contract(q, id_table(live), got, ref, err_msg=path)
 
 
 def test_union_fused_full_probe_matches_exact_oracle(fused_index):

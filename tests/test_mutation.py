@@ -35,7 +35,9 @@ from repro.core.insert import make_insert_fn
 from repro.core.metrics import recall_at_k
 from repro.core.mutate import make_delete_fn, make_update_fn
 from repro.core.rearrange import make_rearrange_fn
+from repro.core.reference import ivf_exact_topk, live_rows
 from repro.core.search import exact_search, make_search_fn, search_union_fused
+from topk_contract import assert_topk_contract, id_table
 
 pytestmark = pytest.mark.mutation
 
@@ -413,9 +415,11 @@ def _churned(dtype, payload="flat", pq_m=0, seed=7):
     ],
 )
 def test_churned_search_all_dtypes(dtype, rerank):
-    """Acceptance: post-churn search (scan impl vs the pure-JAX jnp oracle)
-    returns identical ids, never a deleted id, and every returned id is
-    live."""
+    """Acceptance: post-churn search returns never a deleted id, every
+    returned id is live, and the scan impl agrees with the pure-JAX jnp
+    oracle — for raw f32/bf16 payloads by both meeting the top-k contract
+    against the IVF-exact f32-HIGHEST reference (tests/topk_contract.py),
+    for codes and re-ranked survivors by identical ids."""
     if dtype == "pq":
         oracle, dead, idx = _churned(None, payload="pq", pq_m=8)
     else:
@@ -437,10 +441,16 @@ def test_churned_search_all_dtypes(dtype, rerank):
 
     d_s, i_s = run("scan")
     d_j, i_j = run("jnp")
-    np.testing.assert_array_equal(np.asarray(i_s), np.asarray(i_j))
-    np.testing.assert_allclose(
-        np.asarray(d_s), np.asarray(d_j), rtol=1e-5, atol=1e-5
-    )
+    if dtype in ("float32", "bfloat16") and not rerank:
+        live = live_rows(idx.state)
+        ref = ivf_exact_topk(idx.state, q, nprobe=4, k=10, live=live)
+        for name, got in (("scan", (d_s, i_s)), ("jnp", (d_j, i_j))):
+            assert_topk_contract(q, id_table(live), got, ref, err_msg=name)
+    else:
+        np.testing.assert_array_equal(np.asarray(i_s), np.asarray(i_j))
+        np.testing.assert_allclose(
+            np.asarray(d_s), np.asarray(d_j), rtol=1e-5, atol=1e-5
+        )
     out = np.asarray(i_s)
     found = out[out >= 0]
     assert not np.isin(found, np.asarray(sorted(dead))).any()

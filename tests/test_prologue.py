@@ -3,11 +3,12 @@
 
 Three contracts guard the prologue refactor:
 
-* ``coarse_topk`` (kernel / ``lax.scan`` fallback / jnp oracle) is
-  bit-exact with ``coarse_probe`` — ties included (``top_k`` prefers the
-  lower index; the streaming kernels reproduce it with a (distance, id)
-  sort key) and for N_clusters that is not a multiple of the centroid
-  tile.
+* ``coarse_topk`` (kernel / ``lax.scan`` fallback / jnp oracle) and
+  ``coarse_probe`` meet the top-k contract against the f32-HIGHEST
+  reference (tests/topk_contract.py), also for N_clusters that is not a
+  multiple of the centroid tile; among equal returned distances the lower
+  centroid id comes first (``top_k`` order, which the streaming kernels
+  reproduce with a (distance, id) sort key).
 * ``IVFState.block_owner`` stays consistent with the block table through
   insert -> rearrange -> insert round trips (allocation, recycling via the
   free stack, and compaction all move ownership).
@@ -31,9 +32,11 @@ import jax.numpy as jnp
 
 from repro.core import build_ivf
 from repro.core.block_pool import check_invariants
+from repro.core.reference import exact_topk
 from repro.core.search import coarse_probe, search_union_fused
 from repro.kernels.ivf_scan import coarse_topk, coarse_topk_scan
 from repro.kernels.ref import coarse_topk_ref
+from topk_contract import assert_topk_contract
 
 
 # ---------------------------------------------------------------------------
@@ -64,20 +67,17 @@ def test_coarse_topk_bitexact_with_coarse_probe(q, d, n, nprobe):
     rng = np.random.default_rng(q * 100 + n)
     queries = jnp.asarray(rng.normal(size=(q, d)), jnp.float32)
     cents = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
-    want_i, want_d = _probe(cents, queries, nprobe)
+    ref = exact_topk(cents, queries, nprobe)
     for name, (got_i, got_d) in {
+        "coarse_probe": _probe(cents, queries, nprobe),
         "kernel": coarse_topk(queries, cents, nprobe=nprobe, interpret=True),
         "scan": coarse_topk_scan(queries, cents, nprobe=nprobe),
         "ref": jax.jit(
             lambda c, qs: coarse_topk_ref(qs, c, nprobe=nprobe)
         )(cents, queries),
     }.items():
-        np.testing.assert_array_equal(
-            np.asarray(got_i), np.asarray(want_i), err_msg=name
-        )
-        np.testing.assert_array_equal(
-            np.asarray(got_d), np.asarray(want_d), err_msg=name
-        )
+        assert_topk_contract(queries, cents, (got_d, got_i), ref,
+                             err_msg=name)
 
 
 def test_coarse_topk_breaks_ties_by_centroid_id():
@@ -87,21 +87,23 @@ def test_coarse_topk_breaks_ties_by_centroid_id():
     base = rng.normal(size=(10, 16)).astype(np.float32)
     cents = jnp.asarray(np.repeat(base, 3, axis=0))  # ids 3k,3k+1,3k+2 tie
     queries = jnp.asarray(rng.normal(size=(6, 16)), jnp.float32)
-    want_i, want_d = _probe(cents, queries, 9)
-    want_i = np.asarray(want_i)
+    ref = exact_topk(cents, queries, 9)
     # the construction really does produce in-row ties
-    assert (np.diff(np.asarray(want_d), axis=1) == 0).any()
+    assert (np.diff(np.asarray(ref[0]), axis=1) == 0).any()
     for name, (got_i, got_d) in {
+        "coarse_probe": _probe(cents, queries, 9),
         "kernel": coarse_topk(queries, cents, nprobe=9, interpret=True),
         "scan": coarse_topk_scan(queries, cents, nprobe=9),
         "ref": jax.jit(
             lambda c, qs: coarse_topk_ref(qs, c, nprobe=9)
         )(cents, queries),
     }.items():
-        np.testing.assert_array_equal(np.asarray(got_i), want_i, err_msg=name)
-        np.testing.assert_array_equal(
-            np.asarray(got_d), np.asarray(want_d), err_msg=name
-        )
+        assert_topk_contract(queries, cents, (got_d, got_i), ref,
+                             err_msg=name)
+        # within each run of equal returned distances, lower id first
+        got_d, got_i = np.asarray(got_d), np.asarray(got_i)
+        tie = got_d[:, 1:] == got_d[:, :-1]
+        assert (got_i[:, 1:] > got_i[:, :-1])[tie].all(), name
 
 
 def test_coarse_topk_small_c_tile_covers_multi_tile_merge():
