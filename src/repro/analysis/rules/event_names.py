@@ -8,11 +8,17 @@ matches.  That guarantee only holds if call sites reference the registered
 ``EV_*`` / ``STAGE_*`` constants — an inline string literal re-introduces
 the typo class at every emission site and unmoors grep from the catalog.
 
-This rule flags any ``*.record_event(...)`` or ``*.stamp(...)`` call whose
-first argument is an inline string literal.  Passing the module constant
-(``repro.obs.events`` / ``repro.obs.trace``) is the fix; a deliberate
-literal (e.g. a test asserting the unknown-name ValueError) carries
-``# event-ok: <why>``.
+The same holds for the host spans that ``repro.obs.trace.stage`` writes
+on the profiler's clock (``HOST_SPANS``, named ``<lane>.<stage>``) and for
+profiler annotations: the benchmark's trace readers find a span by its
+name, so an inline literal that drifts from the catalog silences them.
+
+This rule flags any call of ``record_event``, ``stamp``, ``stage`` or
+``TraceAnnotation`` (as a method or a plain function) that
+passes a catalog name -- the event, stage, lane or span -- as an inline
+string literal.  Passing the module constant (``repro.obs.events`` /
+``repro.obs.trace``) is the fix; a deliberate literal (e.g. a test
+asserting the unknown-name ValueError) carries ``# event-ok: <why>``.
 """
 
 from __future__ import annotations
@@ -23,24 +29,37 @@ from typing import List
 from repro.analysis.findings import Finding
 from repro.analysis.lint import LintModule, check_suppression
 
-#: Attribute names whose first positional argument is a catalog name.
-#: ``_stamp`` (the runtime's batch helper) is deliberately absent: its
-#: own body forwards to ``stamp`` and its callers pass constants.
-_EVENT_METHODS = {"record_event", "stamp"}
+#: Callables (method or plain function) -> positions of their positional
+#: arguments that are catalog names.
+_EVENT_CALLS = {
+    "record_event": (0,),
+    "stamp": (0,),
+    "stage": (0, 1),
+    "TraceAnnotation": (0,),
+}
+
+
+def _callee(fn: ast.expr):
+    if isinstance(fn, ast.Attribute):
+        return fn.attr
+    if isinstance(fn, ast.Name):
+        return fn.id
+    return None
 
 
 def check(mod: LintModule) -> List[Finding]:
     findings: List[Finding] = []
     for node in ast.walk(mod.tree):
-        if not isinstance(node, ast.Call) or not node.args:
+        if not isinstance(node, ast.Call):
             continue
-        fn = node.func
-        if not isinstance(fn, ast.Attribute) or fn.attr not in _EVENT_METHODS:
-            continue
-        name = node.args[0]
-        if not (isinstance(name, ast.Constant)
-                and isinstance(name.value, str)):
-            continue  # a Name — the EV_*/STAGE_* convention this rule wants
+        callee = _callee(node.func)
+        literals = [
+            node.args[i].value for i in _EVENT_CALLS.get(callee, ())
+            if i < len(node.args) and isinstance(node.args[i], ast.Constant)
+            and isinstance(node.args[i].value, str)
+        ]
+        if not literals:
+            continue  # Names: the EV_*/STAGE_*/LANE_* convention wanted
         suppressed, extra = check_suppression(mod, node.lineno, "event-ok")
         findings.extend(extra)
         if not suppressed:
@@ -50,9 +69,10 @@ def check(mod: LintModule) -> List[Finding]:
                     path=mod.path,
                     line=node.lineno,
                     message=(
-                        f"inline event/stage name {name.value!r} passed to "
-                        f".{fn.attr}(): emission sites must reference the "
-                        "registered EV_*/STAGE_* constants "
+                        f"inline event/stage/span name "
+                        f"{', '.join(map(repr, literals))} passed to "
+                        f"{callee}(): emission sites must reference the "
+                        "registered EV_*/STAGE_*/LANE_*/SPAN_* constants "
                         "(repro.obs.events / repro.obs.trace) so typos fail "
                         "at import time and grep stays anchored to the "
                         "catalog"

@@ -65,6 +65,86 @@ def percentile_summary(samples_s: Iterable[float]) -> dict:
     }
 
 
+class LatencyHistogram:
+    """Latency samples of one lane in fixed logarithmic buckets.
+
+    Memory does not grow with traffic, so ``stats()`` covers every sample
+    since the last reset, however long the run.  Count, sum, minimum and
+    maximum are exact; a percentile interpolates between the centres of
+    the buckets that hold its two neighbouring order statistics, so it
+    lies within half a bucket (0.5%) of what ``percentile_summary`` gives
+    over the raw samples.  The lowest and highest order statistics are the
+    exact minimum and maximum.  One leaf lock."""
+
+    LO_S = 1e-6  # bucket 0 holds everything up to 1 us ...
+    RATIO = 1.01  # ... and each bucket is 1% wider than the one before
+    N_BUCKETS = math.ceil(math.log(1e4 / LO_S) / math.log(RATIO))  # to 10^4 s
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._log_ratio = math.log(self.RATIO)
+        self._counts = [0] * self.N_BUCKETS  # guarded-by: _lock
+        self._n = 0  # guarded-by: _lock
+        self._sum = 0.0  # guarded-by: _lock
+        self._min = math.inf  # guarded-by: _lock
+        self._max = -math.inf  # guarded-by: _lock
+
+    def _bucket(self, s: float) -> int:
+        if s <= self.LO_S:
+            return 0
+        return min(int(math.log(s / self.LO_S) / self._log_ratio),
+                   self.N_BUCKETS - 1)
+
+    def record(self, seconds: float) -> None:
+        b = self._bucket(seconds)
+        with self._lock:
+            self._counts[b] += 1
+            self._n += 1
+            self._sum += seconds
+            self._min = min(self._min, seconds)
+            self._max = max(self._max, seconds)
+
+    def reset(self) -> None:
+        with self._lock:
+            self._counts = [0] * self.N_BUCKETS
+            self._n, self._sum = 0, 0.0
+            self._min, self._max = math.inf, -math.inf
+
+    def summary(self, timeout_ms: Optional[float] = None) -> dict:
+        """``percentile_summary``'s keys (milliseconds), plus ``timeouts``:
+        samples in buckets above ``timeout_ms`` (within one bucket)."""
+        with self._lock:
+            counts = np.array(self._counts, np.int64)
+            n, total, lo, hi = self._n, self._sum, self._min, self._max
+        if n == 0:
+            return dict(percentile_summary(()), timeouts=0)
+        cum = np.cumsum(counts)
+        centre = self.LO_S * self.RATIO ** (np.arange(self.N_BUCKETS) + 0.5)
+
+        def order_stat(k: int) -> float:
+            if k == 0:
+                return lo
+            if k == n - 1:
+                return hi
+            b = int(np.searchsorted(cum, k, side="right"))
+            return min(max(float(centre[b]), lo), hi)
+
+        def pct(q: float) -> float:
+            rank = q / 100.0 * (n - 1)
+            k = int(math.floor(rank))
+            v = order_stat(k)
+            if rank > k:
+                v += (rank - k) * (order_stat(k + 1) - v)
+            return v * 1e3
+
+        timeouts = 0
+        if timeout_ms:
+            timeouts = int(counts[self._bucket(timeout_ms / 1e3) + 1:].sum())
+        return {"p50_ms": pct(50), "p95_ms": pct(95), "p99_ms": pct(99),
+                "mean_ms": total / n * 1e3, "max_ms": hi * 1e3, "n": n,
+                "timeouts": timeouts}
+
+
 class ArrivalEstimator:
     """Lock-disciplined EWMA tracker for one serving lane.
 
@@ -198,19 +278,6 @@ class LatencyStats:
     max_ms: float
     n: int
     timeouts: int = 0
-
-    @classmethod
-    def from_samples(cls, samples_s: Iterable[float], timeout_ms: float = None):
-        samples = list(samples_s)
-        p = percentile_summary(samples)
-        ms = np.asarray(samples, np.float64) * 1e3
-        timeouts = int((ms > timeout_ms).sum()) if timeout_ms and ms.size \
-            else 0
-        return cls(
-            mean_ms=p["mean_ms"], p50_ms=p["p50_ms"], p95_ms=p["p95_ms"],
-            p99_ms=p["p99_ms"], max_ms=p["max_ms"], n=p["n"],
-            timeouts=timeouts,
-        )
 
     def as_dict(self) -> dict:
         """JSON-ready percentile summary (same keys as

@@ -57,8 +57,10 @@ Fault-tolerance layer (docs/serving_ops.md):
 
 from __future__ import annotations
 
-import collections
+import contextlib
 import dataclasses
+import gc
+import itertools
 import logging
 import os
 import queue
@@ -90,8 +92,8 @@ from repro.core.ivf import IVFIndex, IVFIndexConfig, state_to_host
 from repro.core.metrics import (
     ArrivalEstimator,
     CounterSet,
+    LatencyHistogram,
     LatencyStats,
-    percentile_summary,
 )
 from repro.core.mutate import apply_delete, last_occurrence_mask
 from repro.core import pq as pqmod
@@ -113,19 +115,30 @@ from repro.obs.events import (
     EV_WORKER_RESTART,
     FlightRecorder,
 )
+from repro.obs.programs import (
+    PROGRAM_DELETE,
+    PROGRAM_FUSED,
+    PROGRAM_INSERT,
+    PROGRAM_SEARCH,
+    PROGRAM_UPDATE,
+    jit_program,
+)
 from repro.obs.trace import (
+    LANE_MUTATION,
+    LANE_SEARCH,
     OUTCOME_ERROR,
     OUTCOME_OK,
     OUTCOME_REJECTED,
     OUTCOME_SHED,
     STAGE_ACK,
     STAGE_ADMISSION,
-    STAGE_BATCH,
-    STAGE_COMPILE,
+    STAGE_BUDGET,
     STAGE_DEVICE,
     STAGE_EXECUTE,
     STAGE_QUEUE,
+    GcSpans,
     RequestTracer,
+    stage,
 )
 from repro.persist import snapshot as snapmod
 from repro.persist.snapshot import (
@@ -137,6 +150,11 @@ from repro.persist.snapshot import (
 from repro.persist.wal import MutationWAL
 
 log = logging.getLogger(__name__)
+
+
+def _ns(t: float) -> int:
+    """A ``perf_counter()`` reading in ``perf_counter_ns()`` units."""
+    return int(t * 1e9)
 
 
 @dataclasses.dataclass
@@ -171,9 +189,6 @@ class RuntimeConfig:
     # exact-fp32 re-rank epilogue over the fused survivors (fused paths
     # only; rejected at construction otherwise)
     rerank: bool = False
-    # latency samples kept for stats(); unbounded lists grow forever under
-    # sustained traffic
-    latency_window: int = 10_000
     # run dead-space-reclaiming compaction passes on the mutation lane after
     # a delete/update batch whenever a cluster crosses the dead-fraction
     # trigger (see core.rearrange); off by default — maintenance cadence is
@@ -680,23 +695,16 @@ class ServingRuntime:
                 patience=cfg.adaptive_patience,
                 initial_search=cfg.n_slots,
             )
-        # bounded: stats() reports over a sliding window instead of every
-        # sample since process start.  Appends and snapshots share a lock —
-        # iterating a deque while a worker appends raises RuntimeError
-        # (unlike the copy-a-list-under-GIL idiom it replaced).
-        self._lat_lock = threading.Lock()
-        # guarded-by: _lat_lock
-        self._search_lat: collections.deque = collections.deque(
-            maxlen=cfg.latency_window
-        )
-        # guarded-by: _lat_lock
-        self._insert_lat: collections.deque = collections.deque(
-            maxlen=cfg.latency_window
-        )
-        # guarded-by: _lat_lock
-        self._mutation_lat: collections.deque = collections.deque(
-            maxlen=cfg.latency_window
-        )
+        # submit-to-resolve latency per lane since the last reset_stats(),
+        # in fixed log buckets: every sample counts, in bounded memory
+        self._lat = {lane: LatencyHistogram()
+                     for lane in ("search", "insert", "mutation")}
+        # per-lane dispatch sequence numbers, the ``dispatch`` of each
+        # dispatch's spans and request traces; never reset, so a number
+        # names one dispatch for the runtime's life (next() on a count is
+        # atomic under the interpreter lock)
+        self._dispatch_seq = {LANE_SEARCH: itertools.count(1),
+                              LANE_MUTATION: itertools.count(1)}
         # every counter the runtime bumps lives here: workers, submit paths
         # and the supervisor all increment concurrently, and bare += on
         # instance ints drops increments (see metrics.CounterSet)
@@ -792,6 +800,10 @@ class ServingRuntime:
         ]
         for t in self._threads:
             t.start()
+        # collector pauses as host spans while a profiler runs; removed
+        # by stop()
+        self._gc_spans = GcSpans()
+        gc.callbacks.append(self._gc_spans)
 
     # ------------------------------------------------------------ steps --
     def _build_steps(self):
@@ -832,9 +844,12 @@ class ServingRuntime:
             "insert": _insert, "delete": _delete, "update": _update,
         }
         self._insert_fn = _insert
-        self._insert_step = jax.jit(_insert, donate_argnums=(0,))
-        self._delete_step = jax.jit(_delete, donate_argnums=(0,))
-        self._update_step = jax.jit(_update, donate_argnums=(0,))
+        self._insert_step = jit_program(PROGRAM_INSERT, _insert,
+                                        donate_argnums=(0,))
+        self._delete_step = jit_program(PROGRAM_DELETE, _delete,
+                                        donate_argnums=(0,))
+        self._update_step = jit_program(PROGRAM_UPDATE, _update,
+                                        donate_argnums=(0,))
 
     def _current_budget(self) -> int:  # holds: _state_lock
         """Adaptive chain budget (§Perf), recomputed at *dispatch* time.
@@ -860,10 +875,11 @@ class ServingRuntime:
             # are what actually bound the recompile count; a future budget
             # heuristic must not silently re-introduce
             # one-recompile-per-increment growth.
-            budget = min(
-                self._bucket(2 * self.index._chain_budget(), floor=1),
-                self.pool_cfg.max_chain,
-            )
+            with stage(LANE_SEARCH, STAGE_BUDGET):
+                budget = min(
+                    self._bucket(2 * self.index._chain_budget(), floor=1),
+                    self.pool_cfg.max_chain,
+                )
             # both caches key tuples whose first element is the base budget
             for cache in (self._search_steps, self._fused_steps):
                 for stale in [k for k in cache if k[0] < budget]:
@@ -903,8 +919,8 @@ class ServingRuntime:
         rerank = self.cfg.rerank if rerank is None else rerank
         key = (base, budget, nprobe, rerank)
         if key not in self._search_steps:
-            self._search_steps[key] = jax.jit(
-                self._make_search(budget, nprobe, rerank)
+            self._search_steps[key] = jit_program(
+                PROGRAM_SEARCH, self._make_search(budget, nprobe, rerank)
             )
         return self._search_steps[key]
 
@@ -927,7 +943,9 @@ class ServingRuntime:
                 new_state = _mutate(state, *m_args)
                 return new_state, d, i
 
-            self._fused_steps[key] = jax.jit(_fused, donate_argnums=(0,))
+            self._fused_steps[key] = jit_program(
+                PROGRAM_FUSED, _fused, donate_argnums=(0,)
+            )
         return self._fused_steps[key]
 
     # ------------------------------------------------------------ API ----
@@ -1186,6 +1204,8 @@ class ServingRuntime:
         results cannot be delivered to anyone meaningfully late) and their
         slots released.  No submitted future is ever left unresolved."""
         drain = self.cfg.drain_on_stop if drain is None else drain
+        if self._gc_spans in gc.callbacks:
+            gc.callbacks.remove(self._gc_spans)
         with self._submit_lock:
             self._accepting = False
         self._stop.set()
@@ -1265,10 +1285,8 @@ class ServingRuntime:
         controller rung — is left alone, as is the flight recorder: its
         history of transitions is the point, and post-reset readers still
         want to know what happened before the benchmark phase began."""
-        with self._lat_lock:
-            self._search_lat.clear()
-            self._insert_lat.clear()
-            self._mutation_lat.clear()
+        for hist in self._lat.values():
+            hist.reset()
         self._counters.reset()
         # adaptive/pool sampled state (missed before the obs PR): learned
         # load from one benchmark cell must not steer the next cell
@@ -1279,18 +1297,14 @@ class ServingRuntime:
         self._tracer.ring.clear()
 
     def stats(self, timeout_ms: float = 20.0):
-        with self._lat_lock:
-            search = tuple(self._search_lat)
-            insert = tuple(self._insert_lat)
-            mutation = tuple(self._mutation_lat)
+        lat = {lane: LatencyStats(**hist.summary(timeout_ms))
+               for lane, hist in self._lat.items()}
         c = self._counters.snapshot()
         ladder = self._ladder.snapshot()
         with self._submit_lock:
             accepting = self._accepting
         out = {
-            "search": LatencyStats.from_samples(search, timeout_ms),
-            "insert": LatencyStats.from_samples(insert, timeout_ms),
-            "mutation": LatencyStats.from_samples(mutation, timeout_ms),
+            **lat,  # "search", "insert", "mutation"
             # request outcome counters
             "rejected": c.get("rejected_search", 0),
             "rejected_search": c.get("rejected_search", 0),
@@ -1301,10 +1315,15 @@ class ServingRuntime:
             "isolations": c.get("isolations", 0),
             "fused_fallbacks": c.get("fused_fallbacks", 0),
             "worker_restarts": c.get("worker_restarts", 0),
-            # mutation-stream counters (rows applied, not batches)
+            # dispatch counters: queries / rows applied, and dispatches
+            "search_dispatches": c.get("search_dispatches", 0),
+            "search_queries": c.get("search_queries", 0),
             "inserts": c.get("inserts", 0),
             "deletes": c.get("deletes", 0),
             "updates": c.get("updates", 0),
+            "insert_dispatches": c.get("insert_dispatches", 0),
+            "delete_dispatches": c.get("delete_dispatches", 0),
+            "update_dispatches": c.get("update_dispatches", 0),
             "compactions": c.get("compactions", 0),
             # live gauges
             "pending_mutations": self._gate.pending(),
@@ -1313,13 +1332,12 @@ class ServingRuntime:
             "degradation_level": ladder["level"],
             "degradation_transitions": ladder["transitions"],
             "accepting": accepting,
-            # JSON-ready p50/p95/p99 per lane via the one shared helper
-            # (metrics.percentile_summary) — benchmarks and the runbook
-            # consume these instead of post-processing raw windows
+            # JSON-ready p50/p95/p99 per lane, the same numbers as the
+            # LatencyStats above, with percentile_summary's keys
             "percentiles": {
-                "search": percentile_summary(search),
-                "insert": percentile_summary(insert),
-                "mutation": percentile_summary(mutation),
+                lane: {k: v for k, v in st.as_dict().items()
+                       if k != "timeouts"}
+                for lane, st in lat.items()
             },
         }
         # one locked read: the separate capacity/in_flight property reads
@@ -1476,15 +1494,11 @@ class ServingRuntime:
                     self._tracer.finish(it.trace, OUTCOME_ERROR)
                 self._slots.release()
 
-    @staticmethod
-    def _stamp(items: list[_Timed], stage: str,
-               t: Optional[float] = None) -> None:
-        """Stamp one span boundary on every sampled trace in a batch —
-        unsampled items (``trace is None``, the overwhelming default) cost
-        exactly this None check."""
+    def _finish_ok(self, items: list[_Timed]) -> None:
+        """Seal the sampled traces of a batch whose futures resolved."""
         for it in items:
             if it.trace is not None:
-                it.trace.stamp(stage, t)
+                self._tracer.finish(it.trace, OUTCOME_OK)
 
     @staticmethod
     def _n_rows(it: _Timed) -> int:
@@ -1766,31 +1780,34 @@ class ServingRuntime:
                 # the measured seconds cover everything a dispatch costs
                 n_traced = self._traced(step)
                 t_svc = time.perf_counter()
-                # batch_form span ends here, BEFORE the fault site: an
-                # injected dispatch delay belongs to the dispatch stages
-                self._stamp(items, STAGE_BATCH, t_svc)
-                self._faults.check("mutation_step")
-                if _isolate:  # top-level dispatch: feed the controller
-                    self._controller.mutation.observe_queue_age(
-                        time.perf_counter()
-                        - min(it.t_arrival for it in items)
-                    )
-                args, ids, raw = self._mutation_args(kind, items, ids=ids)
-                with self._state_lock:
-                    if lsn is None:
-                        lsn = self._wal_append(kind, ids, raw)
-                    self.index.state = step(self.index.state, *args)
-                    st = self.index.state
-                    self._budget = None  # chains may have grown
-                # trace-count delta = this dispatch compiled, not executed
-                # from cache (PR 9's detection, reused for the span split)
-                compiled = self._traced(step) != n_traced
-                self._stamp(
-                    items, STAGE_COMPILE if compiled else STAGE_EXECUTE
-                )
-                jax.block_until_ready(st.cluster_len)
+                oldest = min(it.t_arrival for it in items)
+                seq = next(self._dispatch_seq[LANE_MUTATION])
+                # the execute stage closes batch_form as it opens, BEFORE
+                # the fault site: an injected dispatch delay belongs to the
+                # dispatch stages
+                with stage(LANE_MUTATION, STAGE_EXECUTE, items, dispatch=seq,
+                           n=sum(self._n_rows(it) for it in items),
+                           kind=kind, oldest_ns=_ns(oldest)) as ex:
+                    self._faults.check("mutation_step")
+                    if _isolate:  # top-level dispatch: feed the controller
+                        self._controller.mutation.observe_queue_age(
+                            time.perf_counter() - oldest
+                        )
+                    args, ids, raw = self._mutation_args(kind, items, ids=ids)
+                    with self._state_lock:
+                        if lsn is None:
+                            lsn = self._wal_append(kind, ids, raw)
+                        self.index.state = step(self.index.state, *args)
+                        st = self.index.state
+                        self._budget = None  # chains may have grown
+                    # trace-count delta = this dispatch compiled, not
+                    # executed from cache (the compile/execute split)
+                    compiled = self._traced(step) != n_traced
+                    if compiled:
+                        ex.compiled()
+                with stage(LANE_MUTATION, STAGE_DEVICE, items, dispatch=seq):
+                    jax.block_until_ready(st.cluster_len)
                 t_dev = time.perf_counter()
-                self._stamp(items, STAGE_DEVICE, t_dev)
                 if not compiled:  # compile != service
                     self._controller.mutation.observe_service(t_dev - t_svc)
                 if lsn is not None:
@@ -1814,12 +1831,8 @@ class ServingRuntime:
         finally:
             if _isolate:
                 self._record_lock.release()
-        self._counters.inc(
-            {"insert": "inserts", "delete": "deletes",
-             "update": "updates"}[kind],
-            len(ids),
-        )
-        self._resolve_mutations(items, ids)
+        self._count_mutation(kind, len(ids))
+        self._resolve_mutations(items, ids, seq)
         # after the futures resolve: a compaction failure must not fail
         # a mutation that already applied
         if kind != "insert" and self.cfg.auto_compact:
@@ -1836,23 +1849,30 @@ class ServingRuntime:
             take, items = self._split_flush(items)
             self._apply_run(take)
 
-    def _resolve_mutations(self, items: list[_Timed], ids: np.ndarray):
+    def _count_mutation(self, kind: str, rows: int) -> None:
+        """Count one applied mutation dispatch and its rows."""
+        self._counters.inc(
+            {"insert": "inserts", "delete": "deletes",
+             "update": "updates"}[kind],
+            rows,
+        )
+        self._counters.inc(f"{kind}_dispatches")
+
+    def _resolve_mutations(self, items: list[_Timed], ids: np.ndarray,
+                           seq: int):
         """Each future gets exactly the ids of its own rows."""
         t = time.perf_counter()
-        off = 0
-        for it in items:
-            n = self._n_rows(it)
-            with self._lat_lock:
-                lat = self._insert_lat if it.kind == "insert" else \
-                    self._mutation_lat
-                lat.append(t - it.t_arrival)
-            if not it.future.done():
-                it.future.set_result(ids[off : off + n])
-            if it.trace is not None:
-                it.trace.stamp(STAGE_ACK)
-                self._tracer.finish(it.trace, OUTCOME_OK)
-            self._release_gate(it)
-            off += n
+        with stage(LANE_MUTATION, STAGE_ACK, items, dispatch=seq):
+            off = 0
+            for it in items:
+                n = self._n_rows(it)
+                self._lat["insert" if it.kind == "insert" else "mutation"] \
+                    .record(t - it.t_arrival)
+                if not it.future.done():
+                    it.future.set_result(ids[off : off + n])
+                self._release_gate(it)
+                off += n
+        self._finish_ok(items)
 
     def _insert_loop_body(self):
         if self.cfg.mode == "serial":
@@ -1925,45 +1945,48 @@ class ServingRuntime:
                 # full dispatch turnaround, as in _apply_run: the effort
                 # law compares this against the latency envelope
                 t_svc = time.perf_counter()
-                # batch_form ends before the fault site (see _apply_run)
-                self._stamp(items, STAGE_BATCH, t_svc)
-                self._faults.check("search_step")
                 qs = [np.atleast_2d(i.payload) for i in items]
                 counts = [len(q) for q in qs]
-                batch = np.concatenate(qs, 0)
-                pb, valid = self._padded(batch, self._bucket(len(batch)))
-                with self._state_lock:
-                    st = self.index.state
-                    base = self._current_budget()
-                    if _isolate:  # top-level dispatch: feed the ladder
-                        age = time.perf_counter() - min(
-                            i.t_arrival for i in items
+                oldest = min(i.t_arrival for i in items)
+                seq = next(self._dispatch_seq[LANE_SEARCH])
+                # batch_form ends before the fault site (see _apply_run)
+                with stage(LANE_SEARCH, STAGE_EXECUTE, items, dispatch=seq,
+                           n=sum(counts), kind="search",
+                           oldest_ns=_ns(oldest)) as ex:
+                    self._faults.check("search_step")
+                    batch = np.concatenate(qs, 0)
+                    pb, valid = self._padded(batch, self._bucket(len(batch)))
+                    with self._state_lock:
+                        st = self.index.state
+                        base = self._current_budget()
+                        if _isolate:  # top-level dispatch: feed the ladder
+                            age = time.perf_counter() - oldest
+                            level = self._ladder.observe(age)
+                            self._controller.search.observe_queue_age(age)
+                        else:
+                            level = self._ladder.level
+                        # controller effort (steady-state tuning) first,
+                        # ladder rungs (overload protection) on top: both
+                        # halve pow2 values, so the jit-cache key space
+                        # stays bounded
+                        c_nprobe, c_rerank, c_budget = \
+                            self._controller.search_effort(
+                                self.cfg.nprobe, self.cfg.rerank, base
+                            )
+                        nprobe, rerank, eff = self._ladder.apply(
+                            c_nprobe, c_rerank, c_budget, level
                         )
-                        level = self._ladder.observe(age)
-                        self._controller.search.observe_queue_age(age)
-                    else:
-                        level = self._ladder.level
-                    # controller effort (steady-state tuning) first, ladder
-                    # rungs (overload protection) on top: both halve pow2
-                    # values, so the jit-cache key space stays bounded
-                    c_nprobe, c_rerank, c_budget = \
-                        self._controller.search_effort(
-                            self.cfg.nprobe, self.cfg.rerank, base
-                        )
-                    nprobe, rerank, eff = self._ladder.apply(
-                        c_nprobe, c_rerank, c_budget, level
-                    )
-                    step = self._search_step_for(base, eff, nprobe, rerank)
-                    n_traced = self._traced(step)
-                    d, i = step(st, jnp.asarray(pb), jnp.asarray(valid))
-                # trace-count delta = compiled (see _apply_run)
-                compiled = self._traced(step) != n_traced
-                self._stamp(
-                    items, STAGE_COMPILE if compiled else STAGE_EXECUTE
-                )
-                d, i = np.asarray(d), np.asarray(i)
+                        step = self._search_step_for(base, eff, nprobe,
+                                                     rerank)
+                        n_traced = self._traced(step)
+                        d, i = step(st, jnp.asarray(pb), jnp.asarray(valid))
+                    # trace-count delta = compiled (see _apply_run)
+                    compiled = self._traced(step) != n_traced
+                    if compiled:
+                        ex.compiled()
+                with stage(LANE_SEARCH, STAGE_DEVICE, items, dispatch=seq):
+                    d, i = np.asarray(d), np.asarray(i)
                 t_dev = time.perf_counter()
-                self._stamp(items, STAGE_DEVICE, t_dev)
                 if not compiled:  # compile != service
                     self._controller.search.observe_service(t_dev - t_svc)
             except Exception as e:
@@ -1977,23 +2000,28 @@ class ServingRuntime:
                 self._counters.inc("poisoned", len(items))
                 self._fail_futures(items, e)
                 return
-            t = time.perf_counter()
-            off = 0
-            for it, c in zip(items, counts):
-                with self._lat_lock:
-                    self._search_lat.append(t - it.t_arrival)
-                if not it.future.done():
-                    it.future.set_result(
-                        (d[off : off + c], i[off : off + c])
-                    )
-                if it.trace is not None:
-                    it.trace.stamp(STAGE_ACK)
-                    self._tracer.finish(it.trace, OUTCOME_OK)
-                off += c
+            self._counters.inc("search_dispatches")
+            self._counters.inc("search_queries", sum(counts))
+            self._resolve_searches(items, counts, d, i, seq)
         finally:
             if _release:
                 for _ in items:
                     self._slots.release()
+
+    def _resolve_searches(self, items: list[_Timed], counts: list[int],
+                          d: np.ndarray, i: np.ndarray, seq: int):
+        """Each future gets the rows of its own queries."""
+        t = time.perf_counter()
+        with stage(LANE_SEARCH, STAGE_ACK, items, dispatch=seq):
+            off = 0
+            for it, c in zip(items, counts):
+                self._lat["search"].record(t - it.t_arrival)
+                if not it.future.done():
+                    it.future.set_result(
+                        (d[off : off + c], i[off : off + c])
+                    )
+                off += c
+        self._finish_ok(items)
 
     def _serial_mutations(self):
         """Fig. 2a single-lane mode: mutations interleave with (and block)
@@ -2112,59 +2140,75 @@ class ServingRuntime:
             try:
                 # full dispatch turnaround (see _apply_run)
                 t_svc = time.perf_counter()
-                # batch_form ends before the fault site (see _apply_run)
-                self._stamp(s_items, STAGE_BATCH, t_svc)
-                self._stamp(i_run, STAGE_BATCH, t_svc)
-                self._faults.check("fused_step")
                 qs = [np.atleast_2d(x.payload) for x in s_items]
                 counts = [len(q) for q in qs]
-                qbatch = np.concatenate(qs, 0)
-                m_args, ids, raw = self._mutation_args(kind, i_run)
-                pq_, qvalid = self._padded(qbatch, self._bucket(len(qbatch)))
+                oldest = min(x.t_arrival for x in s_items)
+                m_oldest = min(x.t_arrival for x in i_run)
+                seq = next(self._dispatch_seq[LANE_SEARCH])
+                m_seq = next(self._dispatch_seq[LANE_MUTATION])
                 # same per-record discipline as _apply_run: the snapshot
                 # cut is held off from append to fence advance, and the
-                # fence moves only once the device confirmed the apply
-                with self._record_lock:
-                    with self._state_lock:
-                        base = self._current_budget()
-                        now = time.perf_counter()
-                        age = now - min(x.t_arrival for x in s_items)
-                        m_age = now - min(x.t_arrival for x in i_run)
-                        self._controller.search.observe_queue_age(age)
-                        self._controller.mutation.observe_queue_age(m_age)
-                        # controller effort first, ladder protection on top
-                        # (same composition as _run_search)
-                        c_nprobe, c_rerank, c_budget = \
-                            self._controller.search_effort(
-                                self.cfg.nprobe, self.cfg.rerank, base
+                # fence moves only once the device confirmed the apply;
+                # batch_form ends before the fault site (see _apply_run)
+                with contextlib.ExitStack() as record_held:
+                    with (
+                        stage(LANE_SEARCH, STAGE_EXECUTE, s_items,
+                              dispatch=seq, n=sum(counts),
+                              kind="fused",
+                              oldest_ns=_ns(oldest)) as ex,
+                        stage(LANE_MUTATION, STAGE_EXECUTE, i_run,
+                              dispatch=m_seq,
+                              n=sum(self._n_rows(x) for x in i_run),
+                              kind=kind, oldest_ns=_ns(m_oldest)) as m_ex,
+                    ):
+                        self._faults.check("fused_step")
+                        qbatch = np.concatenate(qs, 0)
+                        m_args, ids, raw = self._mutation_args(kind, i_run)
+                        pq_, qvalid = self._padded(
+                            qbatch, self._bucket(len(qbatch)))
+                        record_held.enter_context(self._record_lock)
+                        with self._state_lock:
+                            base = self._current_budget()
+                            now = time.perf_counter()
+                            age = now - oldest
+                            self._controller.search.observe_queue_age(age)
+                            self._controller.mutation.observe_queue_age(
+                                now - m_oldest)
+                            # controller effort first, ladder protection
+                            # on top (same composition as _run_search)
+                            c_nprobe, c_rerank, c_budget = \
+                                self._controller.search_effort(
+                                    self.cfg.nprobe, self.cfg.rerank, base
+                                )
+                            nprobe, rerank, eff = self._ladder.apply(
+                                c_nprobe, c_rerank, c_budget,
+                                self._ladder.observe(age),
                             )
-                        nprobe, rerank, eff = self._ladder.apply(
-                            c_nprobe, c_rerank, c_budget,
-                            self._ladder.observe(age),
-                        )
-                        fused_step = self._fused_step_for(
-                            base, kind, eff, nprobe, rerank
-                        )
-                        n_traced = self._traced(fused_step)
-                        lsn = self._wal_append(kind, ids, raw)
-                        self.index.state, d, i = fused_step(
-                            self.index.state,
-                            jnp.asarray(pq_),
-                            jnp.asarray(qvalid),
-                            *m_args,
-                        )
-                        st = self.index.state
-                        self._budget = None  # chains may have grown/shrunk
-                    # trace-count delta = compiled (see _apply_run)
-                    compiled = self._traced(fused_step) != n_traced
-                    stage = STAGE_COMPILE if compiled else STAGE_EXECUTE
-                    self._stamp(s_items, stage)
-                    self._stamp(i_run, stage)
-                    d, i = np.asarray(d), np.asarray(i)
-                    jax.block_until_ready(st.cluster_len)
+                            fused_step = self._fused_step_for(
+                                base, kind, eff, nprobe, rerank
+                            )
+                            n_traced = self._traced(fused_step)
+                            lsn = self._wal_append(kind, ids, raw)
+                            self.index.state, d, i = fused_step(
+                                self.index.state,
+                                jnp.asarray(pq_),
+                                jnp.asarray(qvalid),
+                                *m_args,
+                            )
+                            st = self.index.state
+                            self._budget = None  # chains may have moved
+                        # trace-count delta = compiled (see _apply_run)
+                        compiled = self._traced(fused_step) != n_traced
+                        if compiled:
+                            ex.compiled()
+                            m_ex.compiled()
+                    with stage(LANE_SEARCH, STAGE_DEVICE, s_items,
+                               dispatch=seq):
+                        d, i = np.asarray(d), np.asarray(i)
+                    with stage(LANE_MUTATION, STAGE_DEVICE, i_run,
+                               dispatch=m_seq):
+                        jax.block_until_ready(st.cluster_len)
                     t_dev = time.perf_counter()
-                    self._stamp(s_items, STAGE_DEVICE, t_dev)
-                    self._stamp(i_run, STAGE_DEVICE, t_dev)
                     if not compiled:
                         svc = t_dev - t_svc
                         self._controller.search.observe_service(svc)
@@ -2179,37 +2223,25 @@ class ServingRuntime:
                 # when the append got through — its WAL record: logging the
                 # run twice would replay it twice on recovery
                 self._apply_run(i_run, _ids=ids, _logged_lsn=lsn)
-                return
-            self._counters.inc(
-                {"insert": "inserts", "delete": "deletes",
-                 "update": "updates"}[kind],
-                len(ids),
-            )
-            t = time.perf_counter()
-            off = 0
-            for it, c in zip(s_items, counts):
-                with self._lat_lock:
-                    self._search_lat.append(t - it.t_arrival)
-                if not it.future.done():
-                    it.future.set_result(
-                        (d[off : off + c], i[off : off + c])
-                    )
-                if it.trace is not None:
-                    it.trace.stamp(STAGE_ACK)
-                    self._tracer.finish(it.trace, OUTCOME_OK)
-                off += c
-            self._resolve_mutations(i_run, ids)
-            if kind != "insert" and self.cfg.auto_compact:
-                try:
-                    self._maybe_compact()
-                except Exception:
-                    log.exception("auto-compact pass failed")
-                    self._counters.inc("compact_errors")
+            else:
+                self._counters.inc("search_dispatches")
+                self._counters.inc("search_queries", sum(counts))
+                self._count_mutation(kind, len(ids))
+                self._resolve_searches(s_items, counts, d, i, seq)
+                self._resolve_mutations(i_run, ids, m_seq)
+                if kind != "insert" and self.cfg.auto_compact:
+                    try:
+                        self._maybe_compact()
+                    except Exception:
+                        log.exception("auto-compact pass failed")
+                        self._counters.inc("compact_errors")
         except Exception as e:
             self._fail_futures(s_items, e)
             self._fail_futures(i_run, e)
         finally:
             for _ in s_items:
                 self._slots.release()
-        if rest:  # later runs / overflow of the drained batch, in order
+        # later runs / overflow of the drained batch, in order — after a
+        # fallback too: returning early there stranded their futures
+        if rest:
             self._apply_mutations(rest)

@@ -27,6 +27,17 @@ Stage model (docs/observability.md):
 Terminal outcomes: ``ok``, ``rejected`` (admission refused), ``shed``
 (deadline passed in queue), ``error`` (lane failure / poison).
 
+Dispatch spans (:func:`stage`): the runtime wraps the work of each
+dispatch -- ``execute``, ``device_wait``, ``ack`` of a lane -- in one
+context manager that stamps the batch's sampled traces on exit and, while
+a profiler session is active, writes a host span named ``<lane>.<stage>``
+(:data:`HOST_SPANS`) into the profiler's own trace.  Every such span
+carries ``t_ns``, the ``perf_counter_ns()`` of its entry: the span's
+start in the profile minus ``t_ns`` maps any ``perf_counter`` stamp onto
+the device trace's clock.  Spans go around work only, never around a
+wait for work, so an idle stretch of the device is labelled by the stage
+the host was in.
+
 Threading: a trace object is only ever touched by the thread that
 currently owns the request (submitter, then exactly one worker loop —
 the queue hand-off provides the happens-before edge), so traces need no
@@ -37,9 +48,10 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import List, Optional, Tuple
+from typing import Iterable, List, Optional, Tuple
 
-from repro.core.metrics import percentile_summary
+from jax._src.lib import _profiler as _xprof
+from jax.profiler import TraceAnnotation
 
 # ---------------------------------------------------------------- catalog --
 # Span stage names.  Register new stages here (and in the table in
@@ -63,6 +75,34 @@ SPAN_STAGES = frozenset({
     STAGE_ACK,
 })
 
+# Host spans on the profiler's clock, ``<lane>.<stage>`` (module
+# docstring).  ``budget`` is a span only: the chain-budget refresh inside
+# a search's ``execute``; no request trace stamps it.
+LANE_SEARCH = "search"
+LANE_MUTATION = "mutation"
+STAGE_BUDGET = "budget"
+
+SPAN_SEARCH_EXECUTE = "search.execute"
+SPAN_SEARCH_BUDGET = "search.budget"
+SPAN_SEARCH_DEVICE = "search.device_wait"
+SPAN_SEARCH_ACK = "search.ack"
+SPAN_MUTATION_EXECUTE = "mutation.execute"
+SPAN_MUTATION_DEVICE = "mutation.device_wait"
+SPAN_MUTATION_ACK = "mutation.ack"
+SPAN_GC = "gc"  # a collection of the interpreter's garbage collector
+
+_SPAN_OF = {
+    (LANE_SEARCH, STAGE_EXECUTE): SPAN_SEARCH_EXECUTE,
+    (LANE_SEARCH, STAGE_BUDGET): SPAN_SEARCH_BUDGET,
+    (LANE_SEARCH, STAGE_DEVICE): SPAN_SEARCH_DEVICE,
+    (LANE_SEARCH, STAGE_ACK): SPAN_SEARCH_ACK,
+    (LANE_MUTATION, STAGE_EXECUTE): SPAN_MUTATION_EXECUTE,
+    (LANE_MUTATION, STAGE_DEVICE): SPAN_MUTATION_DEVICE,
+    (LANE_MUTATION, STAGE_ACK): SPAN_MUTATION_ACK,
+}
+
+HOST_SPANS = frozenset(_SPAN_OF.values()) | {SPAN_GC}
+
 OUTCOME_OK = "ok"
 OUTCOME_REJECTED = "rejected"
 OUTCOME_SHED = "shed"
@@ -77,7 +117,8 @@ class RequestTrace:
     """Span timeline of one request; single-owner, no lock (see module
     docstring for the hand-off argument)."""
 
-    __slots__ = ("trace_id", "kind", "t_start", "marks", "outcome")
+    __slots__ = ("trace_id", "kind", "t_start", "marks", "outcome",
+                 "dispatches")
 
     def __init__(self, trace_id: int, kind: str, t_start: float):
         self.trace_id = trace_id
@@ -88,6 +129,10 @@ class RequestTrace:
         # poison retry re-dispatches), so this is a list, not a dict.
         self.marks: List[Tuple[str, float]] = []
         self.outcome: Optional[str] = None
+        # per-lane sequence numbers of the dispatches this request rode
+        # in (the ``dispatch`` of their ``execute`` spans); a poison retry
+        # re-dispatches, so there may be more than one
+        self.dispatches: List[int] = []
 
     def stamp(self, stage: str, t: Optional[float] = None) -> None:
         """Close the span ending now (or at explicit monotonic ``t``)."""
@@ -118,11 +163,113 @@ class RequestTrace:
             "outcome": self.outcome,
             "t_start": self.t_start,
             "e2e_s": self.e2e_s(),
+            "dispatches": list(self.dispatches),
             "spans": [
                 {"stage": s, "t0": t0, "t1": t1, "dur_s": t1 - t0}
                 for s, t0, t1 in self.spans()
             ],
         }
+
+
+def _stamp_all(items: Iterable, stage: str, t: float) -> None:
+    """Stamp ``stage`` on the sampled trace of every item of a batch (an
+    item carries ``.trace``, a :class:`RequestTrace` or ``None``), all at
+    the same instant.  Unsampled items cost one ``None`` check."""
+    for it in items:
+        if it.trace is not None:
+            it.trace.stamp(stage, t)
+
+
+class _Stage:
+    """The context manager :func:`stage` returns."""
+
+    __slots__ = ("_span", "_stamp_as", "_items", "_meta", "_ann")
+
+    def __init__(self, span: str, stamp_as: str, items, meta: dict):
+        self._span = span
+        self._stamp_as = stamp_as
+        self._items = items
+        self._meta = meta
+        self._ann = None
+
+    def __enter__(self) -> "_Stage":
+        t_ns = time.perf_counter_ns()
+        if _xprof.TraceMe.is_enabled():
+            self._ann = TraceAnnotation(self._span, t_ns=t_ns, **self._meta)
+            self._ann.__enter__()
+        if self._stamp_as == STAGE_EXECUTE:
+            # the batch closes where its dispatch begins: one instant for
+            # the request traces and the span
+            _stamp_all(self._items, STAGE_BATCH, t_ns / 1e9)
+        return self
+
+    def compiled(self) -> None:
+        """This dispatch traced and compiled a new program: the request
+        traces stamp ``compile`` in place of ``execute``, and the span
+        gets ``compiled=1``."""
+        self._stamp_as = STAGE_COMPILE
+        if self._ann is not None:
+            self._ann.set_metadata(compiled=1)
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        # a stage that raised stamps nothing: the failure path finishes
+        # the traces with an ``error`` outcome
+        if exc_type is None:
+            _stamp_all(self._items, self._stamp_as, t)
+        return False
+
+
+def stage(lane: str, stage: str, items: Iterable = (), **meta) -> _Stage:
+    """Context manager around one stage of one dispatch of ``lane``.
+
+    On a clean exit it stamps ``stage`` on every sampled trace of
+    ``items`` at one instant; an ``execute`` stage also stamps
+    ``batch_form`` at its entry, the instant of its ``t_ns``, so the
+    request traces' dispatch boundaries are the span's.  While a profiler
+    session is active it also
+    writes the host span ``<lane>.<stage>`` with ``meta`` and ``t_ns``
+    (``perf_counter_ns()`` at entry) as its stats; otherwise it constructs
+    no profiler object.  An ``execute`` stage with a ``dispatch`` number
+    records it on each sampled trace.  Unregistered names raise
+    ``ValueError``; the ``event-name`` lint rule keeps call sites on the
+    ``LANE_*`` / ``STAGE_*`` constants."""
+    span = _SPAN_OF.get((lane, stage))
+    if span is None:
+        raise ValueError(
+            f"unregistered span {lane}.{stage}; known spans: "
+            f"{sorted(HOST_SPANS)} (register in repro.obs.trace)"
+        )
+    if stage == STAGE_EXECUTE and "dispatch" in meta:
+        for it in items:
+            if it.trace is not None:
+                it.trace.dispatches.append(meta["dispatch"])
+    return _Stage(span, stage, items, meta)
+
+
+class GcSpans:
+    """A ``gc.callbacks`` hook that writes each collection of the
+    interpreter's garbage collector as a ``gc`` host span, with its
+    generation, while a profiler session is active.  A collection holds
+    the interpreter lock, so every thread of the process waits it out;
+    the span names that wait in the device trace's idle gaps."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        # collections do not nest, and one runs start to stop on one
+        # thread, so one open span at a time
+        if phase == "start":
+            if _xprof.TraceMe.is_enabled():
+                self._open = TraceAnnotation(
+                    SPAN_GC, generation=info["generation"])
+                self._open.__enter__()
+        elif self._open is not None:
+            ann, self._open = self._open, None
+            ann.__exit__(None, None, None)
 
 
 class TraceRing:
@@ -235,6 +382,9 @@ def decompose(traces) -> dict:
     end-to-end distribution and the per-trace span-sum distribution.
     Because spans are contiguous, ``span_sum`` equals ``e2e`` up to
     float rounding — exporting both keeps the invariant auditable."""
+    # imported here: repro.core imports the runtime, which imports this
+    from repro.core.metrics import percentile_summary
+
     per_stage: dict = {}
     e2e: List[float] = []
     sums: List[float] = []

@@ -119,25 +119,33 @@ def test_search_batch_poison_fails_only_poisoned_item(base_index):
         rt.stop()
 
 
-def test_fused_step_failure_decomposes_and_isolates(base_index):
+@pytest.mark.parametrize("with_delete", [False, True])
+def test_fused_step_failure_decomposes_and_isolates(base_index, with_delete):
     """A failed fused search+mutation program falls back to the two
-    separate lanes; both sides resolve, nothing hangs."""
+    separate lanes; both sides resolve, nothing hangs.  With a delete in
+    the same drained batch, the delete is a second run after the fused
+    one: the fallback must apply it too (it once returned before the
+    rest of the batch, stranding its futures)."""
     x, make = base_index
     plan = (FaultPlan()
-            .delay("insert_loop", 0.25, nth=0)
+            .delay("insert_loop", 0.25, nth=0)  # both mutations queue up
             .delay("search_loop", 0.35, nth=0)  # wake after insert handoff
             .fail("fused_step", nth=0))
     rt = ServingRuntime(
         make(),
-        RuntimeConfig(mode="fused", nprobe=4, k=5, flush_min=1,
+        RuntimeConfig(mode="fused", nprobe=4, k=5, flush_min=8,
                       flush_interval=0.02),
         faults=plan,
     )
     try:
         sf = rt.submit_search(x[:1])
         mf = rt.submit_insert(_data(4, seed=20))
+        df = rt.submit_delete(np.array([5, 6], np.int32)) \
+            if with_delete else None
         assert _resolved(sf) is None and sf.result()[1][0, 0] == 0
         assert _resolved(mf) is None and len(mf.result()) == 4
+        if with_delete:
+            assert _resolved(df) is None and list(df.result()) == [5, 6]
         assert rt.stats()["fused_fallbacks"] >= 1
     finally:
         rt.stop()

@@ -15,6 +15,7 @@ Three tiers:
   semantics, and the debug bundle written on ``stop()``.
 """
 
+import glob
 import json
 import os
 import re
@@ -27,9 +28,14 @@ import pytest
 from repro.core import build_ivf
 from repro.core.admission import QueueFull
 from repro.core.faults import FaultPlan
-from repro.core.metrics import ArrivalEstimator, percentile_summary
+from repro.core.metrics import (
+    ArrivalEstimator,
+    LatencyHistogram,
+    percentile_summary,
+)
 from repro.core.runtime import RuntimeConfig, ServingRuntime
 from repro.obs import events as obs_events
+from repro.obs import trace as obs_trace
 from repro.obs.bundle import write_debug_bundle
 from repro.obs.events import (
     EV_FAULT_INJECTED,
@@ -48,15 +54,28 @@ from repro.obs.export import (
     perfetto_trace,
     prometheus_text,
 )
+from repro.obs.programs import PROGRAM_INSERT, PROGRAMS, module_name
 from repro.obs.trace import (
+    HOST_SPANS,
+    LANE_SEARCH,
     OUTCOME_OK,
     OUTCOME_REJECTED,
+    SPAN_GC,
+    SPAN_MUTATION_ACK,
+    SPAN_MUTATION_DEVICE,
+    SPAN_MUTATION_EXECUTE,
+    SPAN_SEARCH_ACK,
+    SPAN_SEARCH_DEVICE,
+    SPAN_SEARCH_EXECUTE,
     SPAN_STAGES,
     STAGE_ACK,
     STAGE_ADMISSION,
+    STAGE_BATCH,
     STAGE_COMPILE,
+    STAGE_DEVICE,
     STAGE_EXECUTE,
     STAGE_QUEUE,
+    GcSpans,
     RequestTrace,
     RequestTracer,
     TraceRing,
@@ -500,3 +519,218 @@ def test_runtime_exporters_are_format_valid(base_index):
         assert flat["percentiles_search_n"] == 3.0
     finally:
         rt.stop()
+
+
+# ------------------------------------------- dispatch spans on the profiler --
+def _profiled(tmp_path, body):
+    """Run ``body()`` under a CPU profiler session; return the host events
+    of the recorded trace as ``(name, start_ns, end_ns, stats)``."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    return [(e.name, e.start_ns, e.end_ns, dict(e.stats))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+class _Item:
+    def __init__(self, trace=None):
+        self.trace = trace
+
+
+def test_stage_disabled_path_constructs_no_trace_me(monkeypatch):
+    made = []
+    monkeypatch.setattr(obs_trace, "TraceAnnotation",
+                        lambda *a, **k: made.append(a))
+    tr = RequestTrace(1, "search", time.perf_counter())
+    with obs_trace.stage(LANE_SEARCH, STAGE_EXECUTE, [_Item(tr), _Item()],
+                         dispatch=7, n=2, kind="search") as ex:
+        ex.compiled()
+    hook = GcSpans()
+    hook("start", {"generation": 2})
+    hook("stop", {"generation": 2})
+    assert made == []  # no profiler session: no TraceMe built
+    assert [s for s, _ in tr.marks] == [STAGE_BATCH, STAGE_COMPILE]
+    assert tr.dispatches == [7]
+
+
+def test_stage_rejects_unregistered_span_and_stamps_nothing_on_error():
+    with pytest.raises(ValueError, match="unregistered span"):
+        obs_trace.stage(LANE_SEARCH, "warp_drive")  # event-ok: negative test
+    tr = RequestTrace(1, "search", 0.0)
+    with pytest.raises(RuntimeError):
+        with obs_trace.stage(LANE_SEARCH, STAGE_DEVICE, [_Item(tr)]):
+            raise RuntimeError("lost device")
+    assert tr.marks == []  # the failure path finishes the trace instead
+    assert set(obs_trace._SPAN_OF.values()) < HOST_SPANS
+
+
+def test_lint_rejects_inline_span_names(tmp_path):
+    from repro.analysis.lint import lint_file
+
+    (tmp_path / "snippet.py").write_text(
+        "def dispatch(items):\n"
+        "    with stage('search', STAGE_EXECUTE, items):\n"
+        "        pass\n"
+        "    with stage(LANE_SEARCH, 'device_wait', items):\n"
+        "        pass\n"
+        "    with TraceAnnotation('search.ack'):\n"
+        "        pass\n"
+        "    with stage(LANE_SEARCH, STAGE_ACK, items):\n"
+        "        pass\n"
+    )
+    findings = lint_file("snippet.py", repo_root=str(tmp_path))
+    assert sorted((f.rule, f.line) for f in findings) == [
+        ("event-name", 2), ("event-name", 4), ("event-name", 6)]
+
+
+def test_runtime_dispatch_spans_on_the_profiler_clock(base_index, tmp_path):
+    x, make = base_index
+    rt = ServingRuntime(
+        make(),
+        RuntimeConfig(mode="parallel", nprobe=4, k=5, flush_min=1,
+                      flush_interval=0.02, trace_sample_rate=1.0),
+    )
+    try:
+        rt.submit_search(x[:3]).result(timeout=60)  # compile outside
+        rt.reset_stats()
+
+        def traffic():
+            for j in range(4):
+                rt.submit_search(x[j : j + 2]).result(timeout=60)
+            rt.submit_insert(_data(3, seed=11)).result(timeout=60)
+
+        events = _profiled(tmp_path, traffic)
+        traces = rt.traces()
+        stats = rt.stats()
+    finally:
+        rt.stop()
+    spans = {}
+    for name, a, b, st in events:
+        spans.setdefault(name, []).append((a, b, st))
+    for name in (SPAN_SEARCH_EXECUTE, SPAN_SEARCH_DEVICE, SPAN_SEARCH_ACK,
+                 SPAN_MUTATION_EXECUTE, SPAN_MUTATION_DEVICE,
+                 SPAN_MUTATION_ACK):
+        assert name in spans, name
+    execute = {st["dispatch"]: (a, b, st)
+               for a, b, st in spans[SPAN_SEARCH_EXECUTE]}
+    assert len(execute) == 4
+    for a, b, st in execute.values():
+        assert st["n"] == 2 and st["kind"] == "search"
+        assert st["oldest_ns"] <= st["t_ns"]
+    assert {st["dispatch"] for _, _, st in spans[SPAN_SEARCH_DEVICE]} \
+        == set(execute)
+    assert stats["search_dispatches"] == 4 and stats["search_queries"] == 8
+    assert stats["insert_dispatches"] == 1 and stats["inserts"] == 3
+    searches = [t for t in traces if t.kind == "search"]
+    assert sorted(d for t in searches for d in t.dispatches) \
+        == sorted(execute)
+    for tr in searches:
+        (seq,) = tr.dispatches
+        a, b, st = execute[seq]
+        offset = a - st["t_ns"]  # the anchor: profile ns - perf_counter ns
+        (t0, t1), = [(s0, s1) for s, s0, s1 in tr.spans()
+                     if s == STAGE_EXECUTE]
+        assert abs(t0 * 1e9 + offset - a) < 50e3
+        assert abs(t1 * 1e9 + offset - b) < 50e3
+        assert tr.as_dict()["dispatches"] == [seq]
+
+
+def test_gc_span_while_profiling_and_hook_removed_on_stop(base_index,
+                                                          tmp_path):
+    import gc
+
+    x, make = base_index
+    rt = ServingRuntime(make(), RuntimeConfig(mode="parallel", nprobe=4, k=5))
+    try:
+        assert rt._gc_spans in gc.callbacks
+        events = _profiled(tmp_path, lambda: gc.collect(1))
+    finally:
+        rt.stop()
+    assert rt._gc_spans not in gc.callbacks
+    assert any(name == SPAN_GC and st["generation"] == 1
+               for name, _, _, st in events)
+
+
+def test_runtime_programs_carry_catalog_names(base_index):
+    x, make = base_index
+    rt = ServingRuntime(make(), RuntimeConfig(nprobe=4, k=5))
+    try:
+        with rt._state_lock:
+            steps = [rt._insert_step, rt._delete_step, rt._update_step,
+                     rt._search_step_for(1), rt._fused_step_for(1)]
+    finally:
+        rt.stop()
+    assert {s.__name__ for s in steps} == set(PROGRAMS)
+    lowered = rt._insert_step.lower(
+        rt.index.state, np.zeros((8, D), np.float32),
+        np.zeros(8, np.int32), np.zeros(8, bool))
+    assert f"module @{module_name(PROGRAM_INSERT)} " in lowered.as_text()
+
+
+def _alternatives(pattern: str) -> list:
+    """Each program name a ``^jit__(a|b)$``-style pattern spells out."""
+    m = re.search(r"\(([^()]*)\)", pattern)
+    if m is None:
+        return [pattern]
+    return [alt for a in m[1].split("|")
+            for alt in _alternatives(pattern[:m.start()] + a
+                                     + pattern[m.end():])]
+
+
+def test_benchmark_program_patterns_match_the_catalog():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    patterns = []
+    for path in sorted(glob.glob(os.path.join(root, "bench", "metrics",
+                                              "*.py"))):
+        src = open(path).read()
+        patterns += re.findall(r"""r?["'](\^?jit__[^"']*)["']""", src)
+    assert patterns  # the readers of search_step_ms & co. are there
+    names = [module_name(p) for p in PROGRAMS]
+    for pattern in patterns:
+        for alt in _alternatives(pattern):
+            assert [n for n in names if re.fullmatch(alt.strip("^$"), n)], \
+                f"{alt!r} matches no program of repro.obs.programs"
+
+
+# ------------------------------------------------------ latency histogram --
+@pytest.mark.parametrize("scale_s", [1e-4, 5e-3, 0.3])
+def test_latency_histogram_counts_exact_percentiles_within_a_bucket(scale_s):
+    rng = np.random.default_rng(5)
+    samples = scale_s * rng.lognormal(0.0, 1.0, 20_001)
+    hist = LatencyHistogram()
+    for s in samples:
+        hist.record(float(s))
+    got, want = hist.summary(), percentile_summary(samples)
+    assert got["n"] == want["n"] == len(samples)
+    assert got["max_ms"] == want["max_ms"]
+    assert got["mean_ms"] == pytest.approx(want["mean_ms"], rel=1e-9)
+    for key in ("p50_ms", "p95_ms", "p99_ms"):
+        assert got[key] == pytest.approx(want[key], rel=0.01), key
+    hist.reset()
+    assert hist.summary()["n"] == 0
+
+
+def test_latency_histogram_single_sample_and_timeouts():
+    hist = LatencyHistogram()
+    hist.record(0.25)
+    s = hist.summary(timeout_ms=100.0)
+    assert s["p50_ms"] == s["p99_ms"] == s["max_ms"] == 250.0
+    assert s["timeouts"] == 1
+    assert hist.summary(timeout_ms=300.0)["timeouts"] == 0
+
+
+def test_dispatch_counters_export_as_prometheus_counters():
+    text = prometheus_text({"search_dispatches": 4.0, "search_queries": 9.0,
+                            "insert_dispatches": 1.0})
+    for name in ("search_dispatches", "search_queries", "insert_dispatches"):
+        assert f"# TYPE repro_{name} counter" in text

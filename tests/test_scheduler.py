@@ -334,20 +334,19 @@ def test_malformed_payload_fails_fast_at_submit(base_index):
 
 
 def test_latency_samples_bounded(base_index):
-    """Regression: _search_lat/_insert_lat grew forever under sustained
-    traffic; stats() now reports over a bounded sliding window."""
+    """Regression: the latency samples grew forever under sustained
+    traffic.  stats() now reads fixed log-bucket histograms: memory stays
+    constant while every sample still counts."""
     x, make = base_index
-    rt = ServingRuntime(
-        make(),
-        RuntimeConfig(mode="parallel", nprobe=4, k=5, latency_window=8),
-    )
+    rt = ServingRuntime(make(), RuntimeConfig(mode="parallel", nprobe=4, k=5))
+    hist = rt._lat["search"]
     try:
         for _ in range(3):
             futs = [rt.submit_search(x[:1]) for _ in range(7)]
             for f in futs:
                 f.result(timeout=30)
-        assert len(rt._search_lat) == 8  # maxlen, not 21
-        assert rt.stats()["search"].n == 8
+        assert len(hist._counts) == hist.N_BUCKETS  # fixed, not per sample
+        assert rt.stats()["search"].n == 21  # no cap: every sample counts
     finally:
         rt.stop()
 
