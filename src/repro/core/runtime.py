@@ -157,6 +157,33 @@ def _ns(t: float) -> int:
     return int(t * 1e9)
 
 
+def pack_answers(d: jax.Array, i: jax.Array) -> jax.Array:
+    """A search's f32 distances and int32 ids, both ``[Q, k]``, as one
+    ``int32[Q, 2k]`` buffer: the distances' bit patterns, then the ids.
+    Each separate device-to-host transfer costs a fixed round trip
+    whatever its size, so the answers come back in one.  The bitcast is
+    int32, never f32: no float operation touches ``inf`` or a NaN's
+    bits on the way."""
+    return jnp.concatenate(
+        [jax.lax.bitcast_convert_type(d, jnp.int32), i], axis=1)
+
+
+def unpack_answers(packed: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Inverse of :func:`pack_answers` on the host copy: views, no copy."""
+    k = packed.shape[1] // 2
+    return packed[:, :k].view(np.float32), packed[:, k:]
+
+
+def _packed(search):
+    """``search`` with its ``(d, i)`` answers packed by
+    :func:`pack_answers`, inside the same jitted program."""
+
+    def fn(state, queries, valid):
+        return pack_answers(*search(state, queries, valid))
+
+    return fn
+
+
 @dataclasses.dataclass
 class _Timed:
     future: Future
@@ -920,7 +947,8 @@ class ServingRuntime:
         key = (base, budget, nprobe, rerank)
         if key not in self._search_steps:
             self._search_steps[key] = jit_program(
-                PROGRAM_SEARCH, self._make_search(budget, nprobe, rerank)
+                PROGRAM_SEARCH,
+                _packed(self._make_search(budget, nprobe, rerank)),
             )
         return self._search_steps[key]
 
@@ -934,14 +962,14 @@ class ServingRuntime:
         rerank = self.cfg.rerank if rerank is None else rerank
         key = (base, budget, nprobe, rerank, kind)
         if key not in self._fused_steps:
-            _search = self._make_search(budget, nprobe, rerank)
+            _search = _packed(self._make_search(budget, nprobe, rerank))
             _mutate = self._mutation_fns[kind]
 
             def _fused(state, queries, qvalid, *m_args):
                 # two independent subgraphs; XLA overlaps them (multi-stream)
-                d, i = _search(state, queries, qvalid)
+                answers = _search(state, queries, qvalid)
                 new_state = _mutate(state, *m_args)
-                return new_state, d, i
+                return new_state, answers
 
             self._fused_steps[key] = jit_program(
                 PROGRAM_FUSED, _fused, donate_argnums=(0,)
@@ -1317,6 +1345,7 @@ class ServingRuntime:
             "worker_restarts": c.get("worker_restarts", 0),
             # dispatch counters: queries / rows applied, and dispatches
             "search_dispatches": c.get("search_dispatches", 0),
+            "search_fetches": c.get("search_fetches", 0),
             "search_queries": c.get("search_queries", 0),
             "inserts": c.get("inserts", 0),
             "deletes": c.get("deletes", 0),
@@ -1979,13 +2008,17 @@ class ServingRuntime:
                         step = self._search_step_for(base, eff, nprobe,
                                                      rerank)
                         n_traced = self._traced(step)
-                        d, i = step(st, jnp.asarray(pb), jnp.asarray(valid))
+                        packed = step(st, jnp.asarray(pb), jnp.asarray(valid))
+                    # the copy starts the moment the program ends, not
+                    # when the fetch below asks for it
+                    packed.copy_to_host_async()
                     # trace-count delta = compiled (see _apply_run)
                     compiled = self._traced(step) != n_traced
                     if compiled:
                         ex.compiled()
-                with stage(LANE_SEARCH, STAGE_DEVICE, items, dispatch=seq):
-                    d, i = np.asarray(d), np.asarray(i)
+                with stage(LANE_SEARCH, STAGE_DEVICE, items, dispatch=seq,
+                           fetches=1):
+                    d, i = self._fetch_answers(packed)
                 t_dev = time.perf_counter()
                 if not compiled:  # compile != service
                     self._controller.search.observe_service(t_dev - t_svc)
@@ -2007,6 +2040,14 @@ class ServingRuntime:
             if _release:
                 for _ in items:
                     self._slots.release()
+
+    def _fetch_answers(self, packed: jax.Array
+                       ) -> tuple[np.ndarray, np.ndarray]:
+        """The search lane's one device-to-host transfer per dispatch: the
+        packed answers (:func:`pack_answers`), whose copy the dispatch
+        started at launch, split back into distances and ids."""
+        self._counters.inc("search_fetches")
+        return unpack_answers(np.asarray(packed))
 
     def _resolve_searches(self, items: list[_Timed], counts: list[int],
                           d: np.ndarray, i: np.ndarray, seq: int):
@@ -2189,7 +2230,7 @@ class ServingRuntime:
                             )
                             n_traced = self._traced(fused_step)
                             lsn = self._wal_append(kind, ids, raw)
-                            self.index.state, d, i = fused_step(
+                            self.index.state, packed = fused_step(
                                 self.index.state,
                                 jnp.asarray(pq_),
                                 jnp.asarray(qvalid),
@@ -2197,14 +2238,15 @@ class ServingRuntime:
                             )
                             st = self.index.state
                             self._budget = None  # chains may have moved
+                        packed.copy_to_host_async()  # see _run_search
                         # trace-count delta = compiled (see _apply_run)
                         compiled = self._traced(fused_step) != n_traced
                         if compiled:
                             ex.compiled()
                             m_ex.compiled()
                     with stage(LANE_SEARCH, STAGE_DEVICE, s_items,
-                               dispatch=seq):
-                        d, i = np.asarray(d), np.asarray(i)
+                               dispatch=seq, fetches=1):
+                        d, i = self._fetch_answers(packed)
                     with stage(LANE_MUTATION, STAGE_DEVICE, i_run,
                                dispatch=m_seq):
                         jax.block_until_ready(st.cluster_len)

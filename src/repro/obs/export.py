@@ -31,7 +31,7 @@ PROM_COUNTER_KEYS = frozenset({
     "rejected_search", "rejected_mutation",
     "shed_search", "shed_mutation",
     "inserts", "deletes", "updates",
-    "search_dispatches", "search_queries",
+    "search_dispatches", "search_fetches", "search_queries",
     "insert_dispatches", "delete_dispatches", "update_dispatches",
     "compactions", "compactions_deferred",
     "worker_restarts", "poisoned", "isolations", "fused_fallbacks",

@@ -22,6 +22,7 @@ from repro.configs.anns import ivfflat_sift1m
 from repro.core.block_pool import init_state
 from repro.core.insert import make_insert_fn
 from repro.core.mutate import make_delete_fn
+from repro.core.runtime import pack_answers
 from repro.core.search import make_search_fn
 from repro.kernels.ivf_scan import coarse_topk, ivf_block_scan, ivf_block_topk
 
@@ -107,6 +108,17 @@ def test_block_table_search_step_compiles(pool_cfg, spec, state):
                           chain_budget=1)
     compiled = _compile(step, state, spec((Q, pool_cfg.dim), jnp.float32))
     assert _n_kernels(compiled) == 0  # XLA gathers and matmuls only
+
+
+def test_packed_search_answers_compile(pool_cfg, spec, state):
+    """The runtime's search program ends in ``pack_answers``: one
+    ``int32[Q, 2k]`` output, fetched in one transfer."""
+    step = make_search_fn(pool_cfg, nprobe=NPROBE, k=K, path="block_table",
+                          chain_budget=1)
+    compiled = _compile(lambda st, q: pack_answers(*step(st, q)), state,
+                        spec((Q, pool_cfg.dim), jnp.float32))
+    (out,) = jax.tree.leaves(compiled.out_info)
+    assert out.shape == (Q, 2 * K) and out.dtype == jnp.int32
 
 
 def test_insert_step_compiles(pool_cfg, spec, state):

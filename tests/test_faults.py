@@ -19,6 +19,7 @@ import threading
 import time
 from concurrent.futures import Future
 
+import jax
 import numpy as np
 import pytest
 
@@ -111,6 +112,47 @@ def test_search_batch_poison_fails_only_poisoned_item(base_index):
         assert futs[2].result()[1][0, 0] == 2
         s = rt.stats()
         assert s["poisoned"] == 1 and s["isolations"] == 1
+        # all slots back: a full valid burst succeeds
+        good = [rt.submit_search(x[i : i + 1]) for i in range(8)]
+        for i, f in enumerate(good):
+            assert f.result(timeout=30)[1][0, 0] == i
+    finally:
+        rt.stop()
+
+
+def test_search_fetch_failure_after_launch_resolves_and_isolates(
+        base_index, monkeypatch):
+    """The fetch of the packed answers fails after the launch returned
+    (the way a device error surfaces): fetch 0 is the 3-item batch,
+    fetches 1..3 the per-item retries.  Failing the batch and the middle
+    retry fails only item 1's future, and every slot comes back."""
+    x, make = base_index
+    fetch = ServingRuntime._fetch_answers
+    calls = []
+
+    def failing_fetch(self, packed):
+        assert isinstance(packed, jax.Array)  # the launch has returned
+        calls.append(len(calls))
+        if calls[-1] in (0, 2):
+            raise FaultError("injected failure @ fetch")
+        return fetch(self, packed)
+
+    monkeypatch.setattr(ServingRuntime, "_fetch_answers", failing_fetch)
+    rt = ServingRuntime(
+        make(),
+        RuntimeConfig(mode="parallel", nprobe=4, k=5, n_slots=8),
+        faults=FaultPlan().delay("search_loop", 0.3, nth=0),
+    )
+    try:
+        futs = [rt.submit_search(x[i : i + 1]) for i in range(3)]
+        assert _resolved(futs[0]) is None
+        assert futs[0].result()[1][0, 0] == 0
+        assert isinstance(_resolved(futs[1]), FaultError)
+        assert _resolved(futs[2]) is None
+        assert futs[2].result()[1][0, 0] == 2
+        s = rt.stats()
+        assert s["poisoned"] == 1 and s["isolations"] == 1
+        assert len(calls) == 4
         # all slots back: a full valid burst succeeds
         good = [rt.submit_search(x[i : i + 1]) for i in range(8)]
         for i, f in enumerate(good):

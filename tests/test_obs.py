@@ -629,7 +629,10 @@ def test_runtime_dispatch_spans_on_the_profiler_clock(base_index, tmp_path):
         assert st["oldest_ns"] <= st["t_ns"]
     assert {st["dispatch"] for _, _, st in spans[SPAN_SEARCH_DEVICE]} \
         == set(execute)
+    # one device-to-host transfer per dispatch: the packed answers
+    assert all(st["fetches"] == 1 for _, _, st in spans[SPAN_SEARCH_DEVICE])
     assert stats["search_dispatches"] == 4 and stats["search_queries"] == 8
+    assert stats["search_fetches"] == 4
     assert stats["insert_dispatches"] == 1 and stats["inserts"] == 3
     searches = [t for t in traces if t.kind == "search"]
     assert sorted(d for t in searches for d in t.dispatches) \
@@ -730,7 +733,8 @@ def test_latency_histogram_single_sample_and_timeouts():
 
 
 def test_dispatch_counters_export_as_prometheus_counters():
-    text = prometheus_text({"search_dispatches": 4.0, "search_queries": 9.0,
-                            "insert_dispatches": 1.0})
-    for name in ("search_dispatches", "search_queries", "insert_dispatches"):
+    text = prometheus_text({"search_dispatches": 4.0, "search_fetches": 4.0,
+                            "search_queries": 9.0, "insert_dispatches": 1.0})
+    for name in ("search_dispatches", "search_fetches", "search_queries",
+                 "insert_dispatches"):
         assert f"# TYPE repro_{name} counter" in text
