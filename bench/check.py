@@ -16,24 +16,41 @@ configuration file; exact ones have the limit 0.
   probed list lies closer than the farthest row served, unserved.
 * ``resident_mismatch``: live ids missing, duplicated, or never acked.
 * ``list_mismatch``: live rows stored in a list that is not their nearest.
-* ``stored_mismatch``: stored rows that differ from the sent vector.
+* ``stored_mismatch``: stored rows that differ from the reference's
+  encoding of the sent vector in their list (the reference module's
+  ``stored_numbers`` says what differ means for its payload; a PQ payload
+  adds ``code_mismatch_share``).
 * ``invariant_faults``: breaches of the pool's own bookkeeping.
 * ``kmeans_excess``: by how much the k-means objective of the program's
   centroids over the corpus (the rows the build trained on) exceeds that
   of the benchmark's own k-means, run as the configuration states it, as
-  a share.  Every other number takes the program's centroids as given;
-  this one judges the build that made them.
+  a share.  Every other number takes the program's quantizer as given;
+  this one judges the build that made its centroids.
+* the reference module's ``quantizer_numbers`` judge what the payload adds
+  to the quantizer (PQ: ``codebook_excess``, the same for the codebooks).
+* ``ref_assign_mismatch``: the reference checking itself: of a seeded
+  sample of the rows it assigned on the device (every version against the
+  program's centroids, and the corpus against its own k-means'), those
+  whose list is not their f64 nearest, ties within rounding excepted; the
+  module's ``encode_numbers`` do the same for its encoding (PQ:
+  ``ref_code_mismatch``).
+
+The reference module is the configuration's ``reference``; its contract
+is in ``bench/reference/common.py``.  Each stage's seconds are kept in
+``Checker.seconds``.
 """
 
 from __future__ import annotations
 
+import copy
 import dataclasses
+import time
 from typing import Optional
 
 import numpy as np
 
 from bench.reference import kmeans
-from bench.reference.common import assign, probe, sq_dists
+from bench.reference.common import assign, assign_mismatch, probe, sq_dists
 
 INF = np.inf
 #: ``missed_gap`` when fewer than k rows were served and a live one was not
@@ -106,7 +123,7 @@ class Readback:
 
     live_ids: np.ndarray  # [R] id of every live slot
     live_lists: np.ndarray  # [R] list owning that slot's block
-    stored_rows: dict  # id -> stored vector, for a sample of rows
+    stored_rows: dict  # id -> stored payload row, for a sample of rows
     invariant_faults: int
 
 
@@ -151,22 +168,61 @@ def pool_invariant_faults(st: dict, block_size: int) -> int:
 
 
 class Checker:
-    """Holds the reference's view of the index after the window."""
+    """Holds the reference's view of the index after the window.
 
-    def __init__(self, ref_module, centroids, nprobe: int, k: int,
-                 kmeans_iters: int, versions: Versions, readback: Readback):
+    ``quantizer``: host arrays of the index's trained quantizer,
+    ``centroids`` and, for a PQ payload, ``codebooks``.  ``config``: the
+    configuration's ``nprobe``, ``k`` and ``kmeans_iters``, and what its
+    reference module reads of it."""
+
+    def __init__(self, ref_module, quantizer: dict, config: dict,
+                 versions: Versions, readback: Readback, seed: int = 0):
         self.ref = ref_module
-        self.centroids = np.asarray(centroids, np.float32)
-        self.nprobe, self.k = nprobe, k
-        self.v, self.rb = versions, readback
-        self.best, self.second, self.tie = assign(versions.vecs, centroids)
+        self.quantizer = quantizer
+        self.centroids = np.asarray(quantizer["centroids"], np.float32)
+        self.nprobe, self.k = config["nprobe"], config["k"]
+        self.seed = seed
+        self.v = versions
+        self.seconds = {}
+        self._scorers, self._forms = {}, {}
+        t = time.perf_counter()
+        self.best, self.second, self.tie, dist = assign(versions.vecs,
+                                                        self.centroids)
+        t = self._stage("assign", t)
         v = versions
         corpus = v.start_lo == -INF  # the rows the build trained on
         rows = v.vecs[corpus]
-        own = kmeans.lloyd(rows, len(self.centroids), kmeans_iters)
-        self.kmeans_excess = kmeans.objective(
-            rows, self.centroids, self.best[corpus]) / kmeans.objective(
-            rows, own, assign(rows, own)[0]) - 1.0
+        own = kmeans.lloyd(rows, len(self.centroids), config["kmeans_iters"])
+        own_best, _, _, own_dist = assign(rows, own)
+        # the k-means objective: each row's squared distance to its nearest
+        # centroid, summed in f64
+        self.kmeans_excess = (np.sum(dist[corpus], dtype=np.float64)
+                              / np.sum(own_dist, dtype=np.float64) - 1.0)
+        t = self._stage("k-means", t)
+        rng = np.random.default_rng([seed, 5])
+        self.ref_assign_mismatch = (
+            assign_mismatch(v.vecs, self.centroids, self.best, rng)
+            + assign_mismatch(rows, own, own_best, rng))
+        t = self._stage("assign check", t)
+        self.quantizer_numbers = self.ref.quantizer_numbers(
+            quantizer, rows, self.best[corpus], config,
+            np.random.default_rng([seed, 7]))
+        self._stage("codebooks", t)
+        self._index(readback)
+
+    def with_readback(self, readback: Readback) -> "Checker":
+        """The same reference over another read-back index: what the
+        quantizer's judgement costs is not paid again."""
+        other = copy.copy(self)
+        other.seconds, other._forms = dict(self.seconds), {}
+        other._index(readback)
+        return other
+
+    def _index(self, readback: Readback) -> None:
+        """Where each version is stored, and which lists scan what."""
+        t = time.perf_counter()
+        v = self.v
+        self.rb = readback
         # one version per id that may be live at the end, surely live first
         must, may = v.live_at_end(True), v.live_at_end(False)
         order = np.lexsort((~must, v.ids))
@@ -189,6 +245,26 @@ class Checker:
         self.starts = np.searchsorted(self.primary[self.order],
                                       np.arange(len(self.centroids) + 1))
         self.loose = np.flatnonzero(self.tie & ~self.known)
+        self._stage("versions by list", t)
+
+    def _stage(self, name: str, since: float) -> float:
+        now = time.perf_counter()
+        self.seconds[name] = self.seconds.get(name, 0.0) + now - since
+        return now
+
+    def scorer(self, precision: str):
+        if precision not in self._scorers:
+            self._scorers[precision] = self.ref.Scorer(self.quantizer,
+                                                       precision)
+        return self._scorers[precision]
+
+    def form(self, precision: str) -> np.ndarray:
+        """Every version as the reference at ``precision`` would store it in
+        the list it is scanned in."""
+        if precision not in self._forms:
+            self._forms[precision] = self.scorer(precision).encode(
+                self.v.vecs, self.primary)
+        return self._forms[precision]
 
     def version_of(self, ids: np.ndarray) -> np.ndarray:
         pos = np.minimum(np.searchsorted(self._ids, ids), len(self._ids) - 1)
@@ -197,10 +273,13 @@ class Checker:
     def numbers(self, served: Served, control: Optional[str] = None) -> dict:
         """Every compared number.  ``control`` puts the reference at that
         precision in the program's place: its answers and its stored rows."""
+        t = time.perf_counter()
         if control is not None:
             served = self.control_answers(served, control)
         out = self.served_numbers(served)
+        t = self._stage("served", t)
         out.update(self.state_numbers(control))
+        self._stage("state", t)
         return out
 
     # ------------------------------------------------------------ state --
@@ -213,18 +292,23 @@ class Checker:
         out = {"resident_mismatch": self.resident_mismatch,
                "list_mismatch": int(wrong.sum()),
                "invariant_faults": rb.invariant_faults}
-        ref = self.ref.Scorer(self.centroids, "highest")
-        stored = self.ref.Scorer(self.centroids, control) if control else None
         ids = np.fromiter(rb.stored_rows, np.int64, len(rb.stored_rows))
         vj = self.version_of(ids)
-        bad = int((vj < 0).sum())
-        for i, j in zip(ids[vj >= 0].tolist(), vj[vj >= 0].tolist()):
-            want = ref.encode(self.v.vecs[j : j + 1])[0]
-            got = (stored.encode(self.v.vecs[j : j + 1])[0] if stored
-                   else rb.stored_rows[i])
-            bad += int(not np.array_equal(got, want))
-        out["stored_mismatch"] = bad
+        ok = vj >= 0
+        want = self.form("highest")[vj[ok]]
+        if control:
+            got = self.form(control)[vj[ok]]
+        else:
+            got = np.array([rb.stored_rows[i] for i in ids[ok].tolist()]
+                           ).reshape(want.shape)
+        out.update(self.ref.stored_numbers(want, got))
+        out["stored_mismatch"] += int((~ok).sum())
         out["kmeans_excess"] = self.kmeans_excess
+        out.update(self.quantizer_numbers)
+        out["ref_assign_mismatch"] = self.ref_assign_mismatch
+        out.update(self.scorer("highest").encode_numbers(
+            self.v.vecs, self.primary, self.form("highest"),
+            np.random.default_rng([self.seed, 6])))
         return out
 
     # ---------------------------------------------------------- searches --
@@ -235,19 +319,21 @@ class Checker:
         parts.append(self.loose[np.isin(self.second[self.loose], lists)])
         return np.unique(np.concatenate(parts))
 
-    def score(self, scorer, q: np.ndarray, cand: np.ndarray) -> np.ndarray:
-        return scorer.scores(q, self.v.vecs[cand])
+    def score(self, precision: str, q: np.ndarray,
+              cand: np.ndarray) -> np.ndarray:
+        """Distances of ``q`` to the versions ``cand`` as stored."""
+        return self.scorer(precision).scores(
+            q, self.form(precision)[cand], self.primary[cand])
 
     def served_numbers(self, served: Served) -> dict:
         v = self.v
-        scorer = self.ref.Scorer(self.centroids, "highest")
         sure_p, may_p = probe(served.queries, self.centroids, self.nprobe)
         foreign, dist_gap, missed = 0, 0.0, 0.0
         for j in range(len(served.queries)):
             sent, done = served.sent[j], served.done[j]
             cand = self.candidates(np.flatnonzero(may_p[j]))
             cand = cand[(v.start_lo[cand] < done) & (v.end_hi[cand] > sent)]
-            d = self.score(scorer, served.queries[j], cand)
+            d = self.score("highest", served.queries[j], cand)
             keep = served.ids[j] >= 0
             got = served.ids[j][keep]
             far = []
@@ -274,7 +360,6 @@ class Checker:
         """The reference's own probe and scan of the same queries over the
         rows surely live at each search, at ``precision``."""
         v = self.v
-        scorer = self.ref.Scorer(self.centroids, precision)
         d_c, _ = sq_dists(served.queries, self.centroids, precision)
         top = np.argsort(d_c, axis=1, kind="stable")[:, : self.nprobe]
         ids = np.full((len(top), self.k), -1, np.int64)
@@ -284,7 +369,8 @@ class Checker:
             cand = cand[np.isin(self.primary[cand], top[j])
                         & (v.start_hi[cand] <= served.sent[j])
                         & (v.end_lo[cand] >= served.done[j])]
-            d = self.score(scorer, served.queries[j], cand).astype(np.float32)
+            d = self.score(precision, served.queries[j], cand).astype(
+                np.float32)
             sel = np.argsort(d, kind="stable")[: self.k]
             ids[j, : len(sel)] = v.ids[cand[sel]]
             dists[j, : len(sel)] = d[sel]

@@ -5,7 +5,11 @@ the largest compiles of its set-up and window, and their memory.
 
 * k-means' assignment step over the whole corpus (``kmeans._assign``);
 * the PQ ``block_table`` search step at Q = 8 and 16, budgets 2 and 4;
-* the PQ insert step at B = 1,024.
+* the PQ insert step at B = 1,024;
+* the check's reference programs over every row: the nearest-list
+  assignment (``common.nearest_two``), one step of the reference k-means,
+  the PQ encoding at ``highest`` and at ``fp8``, and one step of the
+  reference's codebook k-means over its training sample.
 
 Each line gives the program's argument, output and temporary bytes as the
 TPU compiler reports them.  A compile that passes is not a chip run.
@@ -90,6 +94,30 @@ def main(argv=None) -> int:
     report(f"PQ insert B={b}",
            insert.lower(state, spec((b, cfg.dim), jnp.float32),
                         spec((b,), jnp.int32), spec((b,), jnp.bool_)))
+
+    from bench.reference import common, ivf_pq
+    from bench.reference import kmeans as ref_kmeans
+
+    rows = -(-n // (1 << 16)) * (1 << 16)  # padded as the check pads them
+    chunk = common.chunk_rows(rows, cfg.n_clusters)
+    x = spec((rows, cfg.dim), jnp.float32)
+    c = spec((cfg.n_clusters, cfg.dim), jnp.float32)
+    report(f"check: nearest_two [{rows}, {cfg.dim}] x [{cfg.n_clusters}, "
+           f"{cfg.dim}], chunk {chunk}",
+           common._nearest_two.lower(x, c, chunk=chunk))
+    report("check: one reference Lloyd step",
+           ref_kmeans._lloyd_step.lower(x, n, c, chunk=chunk))
+    for precision in ("highest", "fp8"):
+        report(f"check: PQ encoding at {precision}",
+               ivf_pq._encode.lower(
+                   x, spec((rows,), jnp.int32), c,
+                   spec(books.codebooks.shape, jnp.float32),
+                   precision=precision, chunk=ivf_pq.ENCODE_CHUNK))
+    report("check: one reference codebook step",
+           ivf_pq._codebook_step.lower(
+               spec((65536, cfg.dim), jnp.float32), 65536,
+               spec(books.codebooks.shape, jnp.float32),
+               chunk=ivf_pq.ENCODE_CHUNK))
     return 0
 
 

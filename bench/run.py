@@ -346,19 +346,31 @@ class Evidence:
     served: Served
     versions: object  # check.Versions
     readback: Readback
-    centroids: np.ndarray
+    quantizer: dict  # host arrays: centroids, and codebooks for PQ
     lost: int  # admitted, and never answered or answered with an error
+    seed: int
+    seconds: dict  # what reading it took, by stage
+
+
+def quantizer(index) -> dict:
+    """The index's trained quantizer as host arrays."""
+    out = {"centroids": np.asarray(index.state.centroids)}
+    if index.pq is not None:
+        out["codebooks"] = np.asarray(index.pq.codebooks)
+    return out
 
 
 def collect(s: Session, d, sched, seed: int) -> Evidence:
     """A seeded sample of the window's answered searches, searches that
     read back acked writes and deletes, and the index as stored."""
+    t = time.perf_counter()
     rng = np.random.default_rng([seed, 3])
     ok = np.flatnonzero(d.search.answered)
     pick = rng.choice(ok, min(len(ok), s.cell.traffic["check_searches"]),
                       replace=False)
     res = [d.search.results[j] for j in pick]
     versions = s.history.versions()
+    t_versions = time.perf_counter()
     recent = np.flatnonzero(versions.start_hi > d.t0)
     gone = np.flatnonzero(versions.end_hi < np.inf)
     again = np.concatenate([
@@ -380,8 +392,12 @@ def collect(s: Session, d, sched, seed: int) -> Evidence:
                             replace=False)]))
     lost = int(sum((lane.admitted & ~lane.answered).sum()
                    for lane in (d.search, d.mutation)))
-    return Evidence(served, versions, readback(s, check_ids),
-                    np.asarray(s.index.state.centroids), lost)
+    t_served = time.perf_counter()
+    rb = readback(s, check_ids)
+    return Evidence(served, versions, rb, quantizer(s.index), lost, seed,
+                    {"versions": t_versions - t,
+                     "read-after-write searches": t_served - t_versions,
+                     "readback": time.perf_counter() - t_served})
 
 
 def checker(cell: Cell, ev: Evidence) -> Checker:
@@ -389,9 +405,8 @@ def checker(cell: Cell, ev: Evidence) -> Checker:
     import importlib
 
     ref = importlib.import_module(f"bench.reference.{cell.config['reference']}")
-    cfg = cell.config
-    return Checker(ref, ev.centroids, cfg["nprobe"], cfg["k"],
-                   cfg["kmeans_iters"], ev.versions, ev.readback)
+    return Checker(ref, ev.quantizer, cell.config, ev.versions, ev.readback,
+                   ev.seed)
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
@@ -433,10 +448,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     gc.collect()
     # the control puts the reference, one precision step down, in the
     # program's place
-    numbers = checker(cell, ev).numbers(
+    chk = checker(cell, ev)
+    numbers = chk.numbers(
         ev.served, control=cell.config["control_precision"] if control else None)
     numbers["lost_answers"] = ev.lost
     log(f"compared at {time.perf_counter() - t_settled:.3f}s")
+    log("check " + json.dumps({n: round(t, 3) for n, t in
+                               {**ev.seconds, **chk.seconds}.items()}))
     limits = cell.config["limits"]
     checks = {n: {"value": numbers[n], "limit": limits[n]} for n in limits}
     correct = all(c["value"] <= c["limit"] for c in checks.values())

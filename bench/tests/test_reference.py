@@ -6,7 +6,12 @@ import pytest
 
 from bench.check import Checker, History, Readback, Served
 from bench.reference import ivf_flat, ivf_pq, kmeans
-from bench.reference.common import assign, dot, probe, sq_dists
+from bench.reference.common import (assign, assign_mismatch, dot, probe,
+                                    sq_dists)
+
+#: what the checker reads of a configuration
+CONFIG = {"nprobe": 4, "k": 10, "kmeans_iters": 10, "pq_iters": 15,
+          "pq_train_rows": 65536}
 
 
 def sift_rows(rng, n, d=128):
@@ -27,12 +32,37 @@ def test_dot_precisions_order():
 def test_assign_is_nearest_list():
     rng = np.random.default_rng(1)
     x, c = sift_rows(rng, 500), sift_rows(rng, 20)
-    best, second, tie = assign(x, c, chunk=64)
+    best, second, tie, dist = assign(x, c, chunk=64)
     d = ((x[:, None].astype(np.float64) - c[None]) ** 2).sum(-1)
     order = np.argsort(d, 1)
     clear = ~tie
     assert (best[clear] == order[clear, 0]).all()
     assert (second[clear] == order[clear, 1]).all()
+    np.testing.assert_allclose(dist, d.min(1), rtol=1e-5)
+
+
+def unit_rows(rng, n, d=64, topics=16):
+    """DSSM-like rows: unit-norm, clustered round a few topics."""
+    x = rng.normal(size=(topics, d))[rng.integers(0, topics, n)]
+    x = x + 0.3 * rng.normal(size=(n, d))
+    return (x / np.linalg.norm(x, axis=1, keepdims=True)).astype(np.float32)
+
+
+def test_assign_is_exact_on_unit_rows():
+    """Many lists close together, as in the DSSM corpus: the device's
+    nearest list is the f64 nearest of every row, ties excepted, and the
+    reference's check of itself reads 0; a wrong list is counted."""
+    rng = np.random.default_rng(11)
+    x, c = unit_rows(rng, 3000), unit_rows(rng, 400)
+    best, second, tie, _ = assign(x, c)
+    d = ((x[:, None].astype(np.float64) - c[None]) ** 2).sum(-1)
+    clear = ~tie
+    assert (best[clear] == d.argmin(1)[clear]).all()
+    assert assign_mismatch(x, c, best, np.random.default_rng(0),
+                           sample=3000) == 0
+    wrong = np.where(clear, second, best)
+    assert assign_mismatch(x, c, wrong, np.random.default_rng(0),
+                           sample=3000) == clear.sum()
 
 
 def test_probe_sure_lists_are_the_nearest():
@@ -50,7 +80,7 @@ def test_pq_scores_are_adc_over_codes():
     rng = np.random.default_rng(3)
     c = rng.normal(size=(4, 8)).astype(np.float32)
     books = rng.normal(size=(2, 256, 4)).astype(np.float32)
-    s = ivf_pq.Scorer(c, books, "highest")
+    s = ivf_pq.Scorer({"centroids": c, "codebooks": books}, "highest")
     x = rng.normal(size=(20, 8)).astype(np.float32)
     lists = rng.integers(0, 4, 20)
     codes = s.encode(x, lists)
@@ -62,14 +92,15 @@ def test_pq_scores_are_adc_over_codes():
     recon = c[lists] + np.concatenate([books[0][codes[:, 0]],
                                        books[1][codes[:, 1]]], 1)
     want = (((q - c[lists]) - (recon - c[lists])) ** 2).sum(-1)
-    np.testing.assert_allclose(s.scores(q, None, lists, codes), want,
-                               rtol=1e-5)
+    np.testing.assert_allclose(s.scores(q, codes, lists), want, rtol=1e-5)
+    fp8 = ivf_pq.Scorer({"centroids": c, "codebooks": books}, "fp8")
+    assert (fp8.encode(x, lists) != codes).any()
 
 
 def _tiny_index(rng, n=600, lists=12):
     x = sift_rows(rng, n)
     c = x[rng.choice(n, lists, replace=False)]
-    best, _, _ = assign(x, c)
+    best = assign(x, c)[0]
     return x, c, best
 
 
@@ -82,8 +113,7 @@ def test_exact_answers_pass_and_high_answers_fail():
     versions = hist.versions()
     rb = Readback(np.arange(len(x)), best,
                   {i: x[i] for i in range(0, len(x), 7)}, 0)
-    chk = Checker(ivf_flat, c, nprobe=4, k=10, kmeans_iters=10,
-                  versions=versions, readback=rb)
+    chk = Checker(ivf_flat, {"centroids": c}, CONFIG, versions, rb)
     q = sift_rows(rng, 40)
     t = np.zeros(len(q))
     blank = Served(q, t, t + 1, np.zeros((40, 10), np.int64),
@@ -93,6 +123,7 @@ def test_exact_answers_pass_and_high_answers_fail():
     assert good["foreign_ids"] == good["resident_mismatch"] == 0
     assert good["dist_gap"] < 0.05 and good["missed_gap"] == 0
     assert good["stored_mismatch"] == good["list_mismatch"] == 0
+    assert good["ref_assign_mismatch"] == 0
     bad = chk.numbers(blank, control="high")
     assert bad["dist_gap"] > 10 * max(good["dist_gap"], 0.01)
 
@@ -107,8 +138,7 @@ def test_checker_catches_wrong_answers(fault):
     versions = hist.versions()
     keep = np.setdiff1d(np.arange(len(x)), gone)
     rb = Readback(keep, best[keep], {}, 0)
-    chk = Checker(ivf_flat, c, nprobe=4, k=10, kmeans_iters=10,
-                  versions=versions, readback=rb)
+    chk = Checker(ivf_flat, {"centroids": c}, CONFIG, versions, rb)
     q = sift_rows(rng, 20)
     t = np.ones(len(q))
     blank = Served(q, t, t + 1, np.zeros((20, 10), np.int64),
@@ -125,19 +155,121 @@ def test_checker_catches_wrong_answers(fault):
     assert n["foreign_ids"] > 0 or n["missed_gap"] > 1.0
 
 
+def _tiny_pq_index(rng, n=600, lists=12, m=4):
+    """Unit rows in their nearest lists, stored as the reference's codes."""
+    x = unit_rows(rng, n, d=16)
+    c = x[rng.choice(n, lists, replace=False)]
+    best, second, tie, _ = assign(x, c)
+    r = (x - c[best]).reshape(n, m, -1)
+    books = np.stack([r[rng.choice(n, 256), j] for j in range(m)])
+    quantizer = {"centroids": c, "codebooks": books.astype(np.float32)}
+    codes = ivf_pq.Scorer(quantizer, "highest").encode(x, best)
+    return x, quantizer, best, second, tie, codes
+
+
+def _pq_checker(x, quantizer, lists, rows):
+    rb = Readback(np.arange(len(x)), lists, rows, 0)
+    return Checker(ivf_pq, quantizer, CONFIG, History(x).versions(), rb)
+
+
+def test_pq_reference_answers_pass_and_fp8_fails():
+    """Through the contract: the reference's own answers and codes pass;
+    its control at fp8 fails on the distance gap and the codes."""
+    rng = np.random.default_rng(7)
+    x, quantizer, best, _, _, codes = _tiny_pq_index(rng)
+    chk = _pq_checker(x, quantizer, best,
+                      {i: codes[i] for i in range(0, len(x), 3)})
+    q = unit_rows(rng, 40, d=16)
+    t = np.zeros(len(q))
+    blank = Served(q, t, t + 1, np.zeros((40, 10), np.int64),
+                   np.zeros((40, 10), np.float32))
+    good = chk.numbers(chk.control_answers(blank, "highest"))
+    assert good["foreign_ids"] == good["resident_mismatch"] == 0
+    assert good["dist_gap"] < 1e-6 and good["missed_gap"] == 0
+    assert good["stored_mismatch"] == good["list_mismatch"] == 0
+    assert good["code_mismatch_share"] == 0
+    assert good["ref_assign_mismatch"] == 0
+    bad = chk.numbers(blank, control="fp8")
+    assert bad["dist_gap"] > 1e-3
+    assert bad["code_mismatch_share"] > 0.1
+
+
+@pytest.mark.parametrize("fault", ["altered_code", "garbled_row",
+                                   "second_list"])
+def test_pq_checker_catches_stored_faults(fault):
+    rng = np.random.default_rng(8)
+    x, quantizer, best, second, tie, codes = _tiny_pq_index(rng)
+    lists = best.copy()
+    rows = {i: codes[i].copy() for i in range(0, len(x), 3)}
+    if fault == "altered_code":
+        rows[0][1] += 1
+    elif fault == "garbled_row":
+        rows[0] = rows[0] ^ np.uint8(1)
+    else:  # stored, and encoded, in the list after its nearest
+        j = np.flatnonzero(~tie)[0]
+        lists[j] = second[j]
+        rows[j] = ivf_pq.Scorer(quantizer, "highest").encode(
+            x[j : j + 1], lists[j : j + 1])[0]
+    n = _pq_checker(x, quantizer, lists, rows).numbers(Served(
+        x[:0], np.zeros(0), np.zeros(0), np.zeros((0, 10), np.int64),
+        np.zeros((0, 10), np.float32)))
+    if fault == "altered_code":
+        assert n["code_mismatch_share"] > 0 and n["stored_mismatch"] == 0
+    elif fault == "garbled_row":
+        assert n["stored_mismatch"] == 1
+    else:
+        assert n["list_mismatch"] == 1
+        assert n["stored_mismatch"] == 0
+
+
 def test_reference_kmeans_objective():
-    """The objective is the plain sum of squared distances to the nearest
-    centroid; Lloyd steps lower it from the starting sample, and the first
-    step lowers it most."""
+    """The objective, the sum of the distances ``assign`` returns, is the
+    plain sum of squared distances to the nearest centroid; Lloyd steps
+    lower it from the starting sample, and the first step lowers it most."""
     rng = np.random.default_rng(6)
     x = sift_rows(rng, 3000)
     costs = []
     for iters in (0, 1, 10):
         c = kmeans.lloyd(x, 12, iters, chunk=512)
-        best, _, _ = assign(x, c)
+        cost = np.sum(assign(x, c)[3], dtype=np.float64)
         d = ((x[:, None].astype(np.float64) - c[None]) ** 2).sum(-1)
-        np.testing.assert_allclose(kmeans.objective(x, c, best, chunk=700),
-                                   d.min(1).sum(), rtol=1e-6)
-        costs.append(kmeans.objective(x, c, best))
+        np.testing.assert_allclose(cost, d.min(1).sum(), rtol=1e-6)
+        costs.append(cost)
     assert costs[0] > costs[1] >= costs[2]
     assert costs[0] - costs[1] > costs[1] - costs[2]
+
+
+def test_pq_reference_checks_its_own_codes():
+    """The reference's device codes agree with an f64 encoding on the
+    host; a code moved to another codeword is counted, once per row."""
+    rng = np.random.default_rng(9)
+    x, quantizer, best, _, _, codes = _tiny_pq_index(rng)
+    s = ivf_pq.Scorer(quantizer, "highest")
+    n = s.encode_numbers(x, best, codes, np.random.default_rng(0),
+                         sample=len(x))
+    assert n == {"ref_code_mismatch": 0}
+    bad = codes.copy()
+    bad[::4, 1] += 1
+    bad[::4, 2] += 1
+    n = s.encode_numbers(x, best, bad, np.random.default_rng(0),
+                         sample=len(x))
+    assert n["ref_code_mismatch"] == len(x[::4])
+
+
+@pytest.mark.parametrize("iters, low, high", [(15, -0.02, 0.02),
+                                              (1, 0.03, np.inf)])
+def test_codebook_excess_reads_the_training(iters, low, high):
+    """Codebooks trained as the configuration states read an excess near
+    0 against the reference's own; codebooks stopped after one Lloyd step
+    read more."""
+    rng = np.random.default_rng(10)
+    x = unit_rows(rng, 6000, d=16)
+    c = x[rng.choice(len(x), 12, replace=False)]
+    best = assign(x, c)[0]
+    res = x - c[best]
+    # the program's training: its own starting codewords and sample
+    start = np.random.default_rng(1).permutation(len(x))
+    books = ivf_pq.train_codebooks(res[start], 4, iters)
+    n = ivf_pq.quantizer_numbers({"centroids": c, "codebooks": books}, x,
+                                 best, CONFIG, np.random.default_rng(2))
+    assert low < n["codebook_excess"] < high, n
